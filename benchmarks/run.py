@@ -16,6 +16,8 @@ SUITES = ["plan_search", "plan_opts", "cache", "conformance", "task_split",
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     want = sys.argv[1:] or SUITES
     failures = []
     for name in want:

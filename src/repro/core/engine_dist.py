@@ -33,9 +33,9 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..compat import shard_map
 from ..distributed.rowstore import RowStoreSpec, make_distributed_fetch
 from ..graph.storage import Graph
 from .engine_jax import build_enumerator, check_jit_supported

@@ -39,7 +39,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..graph.storage import Graph
+from ..graph.storage import Graph, pad_rows, padded_width
 from ..kernels import ops as kops
 from .instructions import (DBQ, ENU, INI, INT, RES, TRC, Instr, Plan, Var)
 from .pattern import Pattern
@@ -50,6 +50,16 @@ FetchFn = Callable[[jax.Array], jax.Array]   # ids int32[B] -> rows int32[B,D]
 # --------------------------------------------------------------------------
 # Device-resident graph
 # --------------------------------------------------------------------------
+
+
+#: rows packed on the host per transfer in ``DeviceGraph.from_graph``
+HOST_BLOCK_ROWS = 1 << 14
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _write_rows(table: jax.Array, part: jax.Array, lo) -> jax.Array:
+    """``table[lo:lo + len(part)] = part``, in place (``table`` donated)."""
+    return jax.lax.dynamic_update_slice(table, part, (lo, 0))
 
 
 @dataclass
@@ -67,18 +77,38 @@ class DeviceGraph:
     @staticmethod
     def from_graph(graph: Graph, d_max: Optional[int] = None,
                    lane: int = 128) -> "DeviceGraph":
-        rows, _ = graph.padded_adjacency(d_max=d_max, lane=lane)
-        rows = np.concatenate(
-            [rows, np.full((1, rows.shape[1]), graph.n, np.int32)], axis=0)
-        return DeviceGraph(rows=jnp.asarray(rows), n=graph.n)
+        """Pack and transfer the table :data:`HOST_BLOCK_ROWS` rows at a
+        time, written in place on device: neither host nor device holds a
+        second copy of it (2.6 GB for a 300k-vertex power-law graph). The
+        sentinel row is an empty adjacency packed with the last block."""
+        adj = list(graph.adj) + [np.zeros(0, np.int64)]
+        d = padded_width(max(len(a) for a in adj), d_max=d_max, lane=lane)
+        rows = jnp.full((len(adj), d), graph.n, jnp.int32)
+        step = HOST_BLOCK_ROWS
+        for lo in range(0, len(adj), step):
+            part = pad_rows(adj[lo:lo + step], graph.n, d_max=d, lane=lane)
+            rows = _write_rows(rows, jnp.asarray(part), lo)
+        return DeviceGraph(rows=rows, n=graph.n)
 
-    def local_fetch(self) -> FetchFn:
-        rows, n = self.rows, self.n
+    @functools.cached_property
+    def lane_rows(self) -> jax.Array:
+        """The rows in the fused kernel's layout (kernels/gather_intersect
+        ``lane_rows``): built on first use, then kept with the graph."""
+        return kops.lane_rows(self.rows, self.n)
 
-        def fetch(ids: jax.Array) -> jax.Array:
-            return rows[jnp.clip(ids, 0, n)]
 
-        return fetch
+def row_fetch(rows: jax.Array, n: int) -> FetchFn:
+    """Local DBQ over ``rows`` (``int32[N+1, D]``, row ``n`` all-holes).
+
+    Inside a jitted program ``rows`` must be one of its arguments: a
+    closed-over device array is embedded in the program as a constant, a
+    host copy of the whole table in every compiled chunk program.
+    """
+
+    def fetch(ids: jax.Array) -> jax.Array:
+        return rows[jnp.clip(ids, 0, n)]
+
+    return fetch
 
 
 # --------------------------------------------------------------------------
@@ -170,6 +200,26 @@ def _apply_filters(sets: jax.Array, filters, env: Dict[Var, jax.Array],
     return out
 
 
+def _flat_cumsum(x: jax.Array) -> jax.Array:
+    """Inclusive prefix sum of a ``[R, C]`` array in flat row-major order.
+
+    Row-wise cumsums plus an exclusive scan of the row totals (recursing
+    in 128-lane rows). Same integers as ``jnp.cumsum(x.reshape(-1))``,
+    but the TPU compiler takes seconds to minutes on one long 1-D cumsum
+    (32 s at 2**20 elements on a v5e target) and under a second on this
+    blocked form.
+    """
+    inner = jnp.cumsum(x, axis=1)
+    tot = inner[:, -1]
+    r = tot.shape[0]
+    if r > 128:
+        tot_p = jnp.pad(tot, (0, -r % 128)).reshape(-1, 128)
+        csum = _flat_cumsum(tot_p)[:r]
+    else:
+        csum = jnp.cumsum(tot)
+    return (inner + (csum - tot)[:, None]).reshape(-1)
+
+
 def _expand(env: Dict[Var, jax.Array], valid: jax.Array,
             cand: jax.Array, target: Var, cap: int, live: frozenset,
             sentinel: int, compaction: str = "cumsum",
@@ -194,7 +244,8 @@ def _expand(env: Dict[Var, jax.Array], valid: jax.Array,
     B, D = cand.shape
     n = B * D
     flat = cand.reshape(n)
-    fvalid = ((cand != sentinel) & valid[:, None]).reshape(n)
+    valid2 = (cand != sentinel) & valid[:, None]
+    fvalid = valid2.reshape(n)
     parent = jnp.repeat(jnp.arange(B, dtype=jnp.int32), D)
     if compaction == "sort":
         order = jnp.argsort(~fvalid, stable=True)    # valid rows first
@@ -202,7 +253,7 @@ def _expand(env: Dict[Var, jax.Array], valid: jax.Array,
         new_valid = fvalid[take]
         parents = parent[take]
     else:
-        pos = jnp.cumsum(fvalid.astype(jnp.int32)) - 1
+        pos = _flat_cumsum(valid2.astype(jnp.int32)) - 1
         slot = jnp.where(fvalid & (pos < cap), pos, cap)
         take = jnp.full((cap + 1,), n, jnp.int32)
         take = take.at[slot].set(jnp.arange(n, dtype=jnp.int32),
@@ -311,15 +362,15 @@ def build_enumerator(plan: Plan,
     slice of V(G); the driver sums counts over chunks. This is the paper's
     |V(G)|/θ subtask split for non-adjacent (u_k1, u_k2), vectorized.
 
-    ``fused_rows`` (the ``[N+1, D]`` device adjacency, row N all-sentinel)
-    turns on the fused fetch path: DBQ targets classified by
-    :func:`classify_fusable_dbqs` stay *lazy* — the engine carries the
-    frontier's id column instead of gathered rows (so ENU re-indexes a
-    ``[B]`` column, not a ``[B, D]`` block) and the consuming INT/TRC
-    runs ``kops.fused_gather_intersect`` (``gather_intersect_impl``
-    selects the kernel; kernels/gather_intersect.py), which never
-    materializes the gathered rows. Results are bit-equal to the unfused
-    path.
+    ``fused_rows`` (the ``[N+1, D]`` device adjacency, row N all-sentinel,
+    or its ``DeviceGraph.lane_rows``) turns on the fused fetch path: DBQ
+    targets classified by :func:`classify_fusable_dbqs` stay *lazy* — the
+    engine carries the frontier's id column instead of gathered rows (so
+    ENU re-indexes a ``[B]`` column, not a ``[B, D]`` block) and the
+    consuming INT/TRC runs ``kops.fused_gather_intersect``
+    (``gather_intersect_impl`` selects the kernel;
+    kernels/gather_intersect.py), which never materializes the gathered
+    rows. Results are bit-equal to the unfused path.
     """
     has_universe = check_jit_supported(plan)
     live = _liveness(plan)

@@ -45,9 +45,9 @@ from typing import Callable, List, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..compat import shard_map
 from ..distributed.rowstore import make_distributed_fetch
 from ..graph.dynamic import SnapshotShardSpec
 from .engine_dist import _rebalancer

@@ -446,7 +446,6 @@ class JaxBackend(ExecutorBackend):
                                  default_caps)
         self.plan, self.graph = plan, source
         self.dg = DeviceGraph.from_graph(source)
-        self.fetch = self.dg.local_fetch()
         self.sentinel = self.dg.n
         self.has_universe = check_jit_supported(plan)
         self._caps0 = tuple(config.caps) if config.caps is not None else \
@@ -473,20 +472,27 @@ class JaxBackend(ExecutorBackend):
     def _runner(self, B: int, caps: Tuple[int, ...]) -> Callable:
         key = (B, caps)
         if key not in self._runners:
-            from .engine_jax import build_enumerator
-            run = build_enumerator(self.plan, self.sentinel, caps, self.fetch,
-                                   collect_matches=self._collect,
-                                   intersect_impl=self._intersect,
-                                   compaction=self._compaction,
-                                   fused_rows=(self.dg.rows if self.fused
-                                               else None),
-                                   gather_intersect_impl=self._gi_impl)
+            from .engine_jax import build_enumerator, row_fetch
+            plan, sentinel = self.plan, self.sentinel
+            kw = dict(collect_matches=self._collect,
+                      intersect_impl=self._intersect,
+                      compaction=self._compaction,
+                      gather_intersect_impl=self._gi_impl)
+
+            # the adjacency (and its lane-row view) are arguments, never
+            # closed over: jit would embed them in the program as constants
+            def run(rows, lanes, *args):
+                return build_enumerator(plan, sentinel, caps,
+                                        row_fetch(rows, sentinel),
+                                        fused_rows=lanes, **kw)(*args)
+
             self._runners[key] = self._jit(run)
         return self._runners[key]
 
     def run_chunk(self, ids, valid, universe_chunk, caps) -> ChunkResult:
         import jax.numpy as jnp
-        args = (jnp.asarray(ids), jnp.asarray(valid))
+        args = (self.dg.rows, self.dg.lane_rows if self.fused else None,
+                jnp.asarray(ids), jnp.asarray(valid))
         if universe_chunk is not None:
             args = args + (jnp.asarray(universe_chunk),)
         res = self._runner(ids.shape[0], caps)(*args)
@@ -518,11 +524,11 @@ class JaxGpuBackend(JaxBackend):
     BENU's hot loop — gather adjacency rows, intersect with the candidate
     set — is memory-bound; this backend keeps it in VMEM/registers
     (kernels/gather_intersect.py) instead of round-tripping a ``[B, D]``
-    gather block through HBM. On a real GPU/TPU the dispatch registry
-    resolves the fused kernel to the compiled Pallas path; on the CPU CI
-    container it falls back to the unfused reference unless interpret
-    mode is forced (``gather_intersect_impl="interpret"`` or
-    ``REPRO_GATHER_INTERSECT_IMPL=pallas-interpret``), which is how the
+    gather block through HBM. On a TPU the dispatch registry resolves the
+    fused kernel to the compiled Pallas path (chip_smoke.py refuses to
+    run otherwise); on a CPU it falls back to the unfused reference
+    unless interpret mode is forced (``gather_intersect_impl="interpret"``
+    or ``REPRO_GATHER_INTERSECT_IMPL=pallas-interpret``), which is how the
     conformance matrix covers it. Counts and match sets are bit-equal to
     ``jax`` either way. Fusion defaults on; ``REPRO_FUSED_FETCH=0``
     turns it off (A/B debugging) without leaving this backend.

@@ -34,7 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..graph.storage import Graph
+from ..graph.storage import Graph, pad_rows
 
 
 @dataclass
@@ -61,14 +61,14 @@ def build_row_shards(graph: Graph, n_shards: int, hot: int = 0,
     Row ``n`` (the sentinel row, all holes) is stored like any other row, so
     gathers with invalid ids round-trip safely.
     """
-    rows, _ = graph.padded_adjacency(d_max=d_max, lane=lane)
-    n, d = graph.n, rows.shape[1]
-    rows = np.concatenate([rows, np.full((1, d), n, np.int32)], axis=0)
+    n = graph.n
     rps = -(-(n + 1) // n_shards)
-    pad = n_shards * rps - (n + 1)
-    if pad:
-        rows = np.concatenate(
-            [rows, np.full((pad, d), n, np.int32)], axis=0)
+    # the sentinel row and the shard padding are empty adjacencies, packed
+    # in the same pass (one host copy of the table)
+    empty = np.zeros(0, np.int64)
+    rows = pad_rows(list(graph.adj) + [empty] * (n_shards * rps - n), n,
+                    d_max=d_max, lane=lane)
+    d = rows.shape[1]
     shards = rows.reshape(n_shards, rps, d)
     hot = min(hot, n)
     # relabeling is ascending-degree, so the hot set is ids [n-hot, n]

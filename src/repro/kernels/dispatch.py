@@ -11,8 +11,8 @@ it:
   environment override (``REPRO_<OP>_IMPL``, e.g. ``REPRO_INTERSECT_IMPL``
   — the CI hook that forces the Pallas path in interpret mode on the CPU
   container); otherwise the registry's platform × width default applies.
-* **tile selection** (:func:`pick_tiles`) — the benchmark-driven
-  ``(bm, bk)`` table per op and platform (``bm`` rows per block along the
+* **tile selection** (:func:`pick_tiles`) — the ``(bm, bk)`` table per
+  op and platform (``bm`` rows per block along the
   batch axis, ``bk`` lanes per chunk along the set-width axis), with
   per-call overrides, clamped so ``bk`` divides the padded width and
   ``bm`` divides the batch.
@@ -148,20 +148,24 @@ def resolve_impl(op: str, impl: str = "auto",
 # Tile-size table (the autotune layer)
 # --------------------------------------------------------------------------
 
-#: benchmark-driven (bm, bk) per op x platform, bucketed by set width:
+#: (bm, bk) per op x platform, bucketed by set width:
 #: ``(max_width_inclusive | None, bm, bk)`` rows, first match wins. The TPU
-#: rows follow the VMEM budget math in kernels/sorted_intersect.py (compare
-#: working set = bm * W * bk bools; <= ~4MiB on a 16MiB v5e core); the
-#: ``'*'`` rows were measured with ``benchmarks/roofline.py --fused`` in
-#: interpret mode on the 2-core CI container (wider bk only pays off once
-#: rows exceed ~1k lanes). Override per call via pick_tiles(bm=, bk=).
+#: rows are compile-checked, not benchmarked: tests/test_tpu_compile.py
+#: compiles both kernels for a v5e at the tilings these rows give (widths
+#: 128..4096). ``bm`` is a multiple of 8 (a block's second-to-last
+#: dimension must be) and ``bk`` = 128 keeps the compare working set at
+#: ``bm * bk * 128`` int32 whatever the row width (budget math in
+#: kernels/sorted_intersect.py). Chip sweeps to refill them are ROADMAP A7.
+#: The ``'*'`` rows were measured with ``benchmarks/roofline.py --fused``
+#: in interpret mode on a 2-core CPU container (wider bk only pays off
+#: once rows exceed ~1k lanes). Override per call via pick_tiles(bm=, bk=).
 TILE_TABLE: Dict[str, Dict[str, Tuple[Tuple[Optional[int], int, int], ...]]] = {
     "intersect": {
-        "tpu": ((512, 8, 128), (2048, 8, 256), (None, 4, 256)),
+        "tpu": ((None, 8, 128),),
         "*": ((None, 8, 128),),
     },
     "gather_intersect": {
-        "tpu": ((1024, 8, 128), (None, 8, 256)),
+        "tpu": ((None, 8, 128),),
         "gpu": ((None, 16, 128),),
         "*": ((None, 8, 128),),
     },

@@ -22,7 +22,7 @@ import jax.numpy as jnp
 
 from . import dispatch, ref
 from .flash_attention import flash_attention_pallas
-from .gather_intersect import gather_intersect_pallas
+from .gather_intersect import LANE, gather_intersect_pallas, lane_rows
 from .rmsnorm import rmsnorm_pallas
 from .sorted_intersect import sorted_intersect_pallas
 
@@ -65,8 +65,9 @@ def intersect_padded(a: jax.Array, b: jax.Array, sentinel: int,
     """Row-wise padded-set intersection; see kernels/ref.py for semantics.
 
     a: int32[B, Da], b: int32[B, Db] (widths may differ — the Pallas path
-    pads both operands to the wider width via dispatch.pad_operands;
-    holes are sentinel-valued so padding never adds members). ``impl``:
+    pads both operands to the wider width, rounded up to 128 lanes, via
+    dispatch.pad_operands; holes are sentinel-valued so padding never adds
+    members). ``impl``:
     auto | pallas | ref | chunked | binary | interpret (alias
     ``pallas-interpret``); resolution order in kernels/dispatch.py.
     ``binary`` needs ``b`` rows fully ascending (holes only in the tail)
@@ -83,12 +84,18 @@ def intersect_padded(a: jax.Array, b: jax.Array, sentinel: int,
         return ref.sorted_intersect_binary(a, b, sentinel)
     interpret = impl == "interpret"
     B, Da = a.shape
-    W = max(Da, b.shape[1])
+    W = _lane_width(max(Da, b.shape[1]))
     bm, bk = dispatch.pick_tiles("intersect", B, W, bm=bm, bk=bk)
-    ap, bp = dispatch.pad_operands(a, b, sentinel, bm)
+    ap, bp = dispatch.pad_operands(dispatch.pad_to(a, 1, W, sentinel), b,
+                                   sentinel, bm)
     out = sorted_intersect_pallas(ap, bp, sentinel, bm=bm, bk=bk,
                                   interpret=interpret)
     return out[:B, :Da]
+
+
+def _lane_width(width: int) -> int:
+    """``width`` rounded up to whole 128-lane vregs (the Pallas paths)."""
+    return -(-width // LANE) * LANE
 
 
 # --------------------------------------------------------------------------
@@ -105,9 +112,11 @@ def fused_gather_intersect(cand: jax.Array, ids: jax.Array,
     The DBQ-level gather and the candidate-set intersection in one kernel
     launch: cand int32[B, Dc] padded sets, ids int32[B] frontier row
     indices (any values — clipped to the sentinel row), rows int32[N+1, D]
-    padded adjacency whose row N is all-sentinel. Returns int32[B, Dc] in
-    ``cand``'s slots — bit-equal to
-    ``intersect_padded(cand, rows[clip(ids)], sentinel)``.
+    padded adjacency whose row N is all-sentinel — or the same table as
+    :func:`~repro.kernels.gather_intersect.lane_rows`, the layout the
+    kernel DMAs from (engines pass that, built once per graph, so no call
+    relayouts the table). Returns int32[B, Dc] in ``cand``'s slots —
+    bit-equal to ``intersect_padded(cand, rows[clip(ids)], sentinel)``.
 
     ``impl``: auto | pallas | interpret fuse on device
     (kernels/gather_intersect.py); ref | chunked | binary fall back to
@@ -119,17 +128,21 @@ def fused_gather_intersect(cand: jax.Array, ids: jax.Array,
     impl = dispatch.resolve_impl("gather_intersect", impl,
                                  width=rows.shape[-1])
     ids = jnp.clip(ids, 0, sentinel)
+    n_rows = sentinel + 1
     if impl in ("ref", "chunked", "binary"):
-        return intersect_padded(cand, rows[ids], sentinel, impl=impl)
+        adj = rows.reshape(n_rows, -1)       # either layout -> [N+1, D]
+        return intersect_padded(cand, adj[ids], sentinel, impl=impl)
     interpret = impl == "interpret"
+    lanes = lane_rows(rows, sentinel) if rows.shape[0] == n_rows else rows
     B, Dc = cand.shape
-    D = rows.shape[1]
-    bm, bk = dispatch.pick_tiles("gather_intersect", B, D, bm=bm, bk=bk)
-    cp = dispatch.pad_to_multiple(cand, 0, bm, sentinel)
+    W = _lane_width(Dc)
+    bm, bk = dispatch.pick_tiles("gather_intersect", B, W, bm=bm, bk=bk)
+    cp = dispatch.pad_to_multiple(dispatch.pad_to(cand, 1, W, sentinel), 0,
+                                  bm, sentinel)
     ip = dispatch.pad_to_multiple(ids, 0, bm, sentinel)
-    out = gather_intersect_pallas(ip, cp, rows, sentinel, bm=bm, bk=bk,
+    out = gather_intersect_pallas(ip, cp, lanes, sentinel, bm=bm, bk=bk,
                                   interpret=interpret)
-    return out[:B]
+    return out[:B, :Dc]
 
 
 # --------------------------------------------------------------------------

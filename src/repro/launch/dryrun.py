@@ -1,11 +1,16 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
+                           " --xla_force_host_platform_device_count=512")
 
 """Multi-pod dry-run: lower + compile every (architecture x shape) cell on
 the production mesh and extract roofline terms from the compiled artifact.
 
-The two lines above MUST stay the first statements of this module (jax
-locks the device count at first init); do not move them below the imports.
+The lines above MUST stay the first statements of this module (jax locks
+the platform and the device count at first init); do not move them below
+the imports. The 512 devices are forced host devices, so the CPU platform
+is pinned: on a chip machine JAX would otherwise pick the attached chips
+and ``make_production_mesh`` would find too few.
 
 Usage:
     python -m repro.launch.dryrun --arch qwen2-0.5b --shape train_4k
@@ -51,10 +56,8 @@ def analyze_cell(arch: str, shape: str, multi_pod: bool,
     compiled = lowered.compile()
     t_compile = time.time() - t0
 
-    from ..compat import cost_analysis_dict
     mem = compiled.memory_analysis()
-    # list-of-dicts on older jax; one dict on newer — normalize
-    cost = cost_analysis_dict(compiled)
+    cost = dict(compiled.cost_analysis() or {})
     hlo = compiled.as_text()
 
     # loop-aware accounting (XLA's cost_analysis counts while bodies once —

@@ -7,10 +7,11 @@
 
 ``--engine jax-gpu`` runs the accelerator fetch path: single-use DBQ
 gathers fuse into the intersect kernel (kernels/gather_intersect.py, see
-docs/KERNELS.md) so gathered row blocks never round-trip through HBM; on
-this CPU container pass ``--gather-intersect-impl interpret`` to run the
-Pallas kernel in interpret mode (otherwise it falls back to the unfused
-reference, still exact).
+docs/KERNELS.md) so gathered row blocks never round-trip through HBM; on a
+TPU it runs the compiled Pallas kernel; on a CPU-only machine pass
+``--gather-intersect-impl interpret`` to run the Pallas kernel in
+interpret mode (otherwise it falls back to the unfused reference, still
+exact).
 
 ``--engine oocache`` runs the out-of-core fetch path: adjacency rows live
 in host-RAM shards, device memory holds only a bounded row cache
@@ -119,7 +120,8 @@ def main():
                          "interpret | ref/chunked/binary fallbacks); "
                          "'interpret' runs the Pallas kernel on CPU")
     ap.add_argument("--devices", type=int, default=0,
-                    help="force N host devices (set before jax init)")
+                    help="run on N forced host devices: pins the CPU "
+                         "platform (set before jax init)")
     ap.add_argument("--batch-per-shard", type=int, default=256)
     ap.add_argument("--hot", type=int, default=64,
                     help="replicated/pinned hot rows: top-degree for "
@@ -147,8 +149,15 @@ def main():
     args = ap.parse_args()
 
     if args.devices:
+        # forced host devices exist only on the CPU backend: pin it, or a
+        # chip machine would silently run on its own devices instead
+        os.environ["JAX_PLATFORMS"] = "cpu"
         os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={args.devices}")
+            os.environ.get("XLA_FLAGS", "") +
+            f" --xla_force_host_platform_device_count={args.devices}"
+        ).strip()
+    from .compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     if args.engine in ("sbenu", "sbenu-jax", "sbenu-dist"):
         _run_continuous(args)

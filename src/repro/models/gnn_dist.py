@@ -35,9 +35,9 @@ from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..compat import shard_map
 from ..layers.mlp import mlp_apply
 from .gnn import GNNConfig, _ln
 

@@ -26,8 +26,8 @@ from typing import Any, Callable, Dict, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 
-from ..compat import shard_map
 from ..distributed.compression import compressed_psum, plain_psum_mean
 from .checkpoint import CheckpointManager
 from .optimizer import AdamWConfig, AdamWState, adamw_init, adamw_update

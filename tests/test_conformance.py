@@ -109,6 +109,30 @@ def test_jax_gpu_fused_forced_overflow_match_set_exact():
     assert gpu.chunks_split > 0
 
 
+@pytest.mark.parametrize("engine", ["jax", "jax-gpu"])
+def test_chunk_program_takes_adjacency_as_argument(engine):
+    """The device adjacency is an argument of every compiled chunk program,
+    never a constant inside it: jit copies a closed-over table into each
+    program, which on a real-size graph is gigabytes of host memory."""
+    import jax.numpy as jnp
+    g = GRAPHS["pl"]
+    plan = generate_best_plan(get_pattern("triangle"), g.stats())
+    kw = {"gather_intersect_impl": "interpret"} if engine == "jax-gpu" else {}
+    ex = make_executor(engine, **kw)
+    assert ex.run(plan, g, batch=32).count == brute_count("triangle", g)
+    be = ex.backend
+    (B, _), runner = next(iter(be._runners.items()))
+    lanes = be.dg.lane_rows if be.fused else None
+    text = runner.lower(be.dg.rows, lanes, jnp.zeros(B, jnp.int32),
+                        jnp.zeros(B, bool)).as_text()
+    tables = {"x".join(map(str, be.dg.rows.shape))}
+    if lanes is not None:
+        tables.add("x".join(map(str, lanes.shape)))
+    baked = [ln for ln in text.splitlines() if "constant" in ln
+             and any(f"tensor<{t}xi32>" in ln for t in tables)]
+    assert not baked, baked[:1]
+
+
 # --------------------------------------------------------------------------
 # oocache == brute on every pattern x graph, with the device cache bounded
 # below 25% of the graph's rows (ISSUE 3 acceptance bar): the host-RAM
@@ -143,6 +167,7 @@ def test_oocache_conformance_bounded_device_cache(pname, gname):
 @pytest.mark.slow
 def test_three_engine_conformance_exact():
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"          # forced host devices, off-chip
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
     code = textwrap.dedent("""
@@ -497,6 +522,7 @@ def test_sbenu_dist_eight_way_stream_matrix():
     rebalancer capacities not divisible by the mesh size
     (`assert cap % n_shards == 0`, core/engine_dist.py)."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"          # forced host devices, off-chip
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
     code = textwrap.dedent("""

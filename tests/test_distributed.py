@@ -16,6 +16,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 def run_sub(code: str, devices: int = 8, timeout: int = 420) -> str:
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"          # forced host devices, off-chip
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
     out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
@@ -66,7 +67,7 @@ def test_rowstore_fetch_unit():
     out = run_sub("""
         import numpy as np, jax, jax.numpy as jnp, json
         from jax.sharding import Mesh, PartitionSpec as P, NamedSharding
-        from repro.compat import shard_map
+        from jax import shard_map
         from repro.distributed.rowstore import (build_row_shards,
                                                 make_distributed_fetch)
         from repro.graph.generate import erdos_renyi
@@ -115,7 +116,7 @@ def _fetch_rows(g, hot, ids, req_cap=64):
     import jax
     import jax.numpy as jnp
     from jax.sharding import Mesh, PartitionSpec as P
-    from repro.compat import shard_map
+    from jax import shard_map
     from repro.distributed.rowstore import (build_row_shards,
                                             make_distributed_fetch)
     import numpy as np
@@ -189,7 +190,7 @@ def test_int8_compressed_psum_error_feedback():
     out = run_sub("""
         import numpy as np, jax, jax.numpy as jnp, json
         from jax.sharding import Mesh, PartitionSpec as P
-        from repro.compat import shard_map
+        from jax import shard_map
         from repro.distributed.compression import (compressed_psum,
                                                    plain_psum_mean)
         mesh = Mesh(np.array(jax.devices()), ("d",))

@@ -70,6 +70,23 @@ def test_subgraph_count_via_automorphisms():
     assert res["count"] == cnt         # symmetry breaking = 1 match/subgraph
 
 
+@pytest.mark.parametrize("block", [1, 7, 64, 1 << 14])
+def test_device_graph_blocked_build_matches_padded_table(block, monkeypatch):
+    """The table built on device block by block is the host padded table
+    plus the all-sentinel row, whatever the block size."""
+    from repro.core import engine_jax
+    from repro.core.engine_jax import DeviceGraph
+    monkeypatch.setattr(engine_jax, "HOST_BLOCK_ROWS", block)
+    g = GRAPHS["pl"]
+    rows, _ = g.padded_adjacency(lane=128)
+    want = np.concatenate([rows, np.full((1, rows.shape[1]), g.n,
+                                         np.int32)])
+    dg = DeviceGraph.from_graph(g)
+    np.testing.assert_array_equal(np.asarray(dg.rows), want)
+    with pytest.raises(ValueError, match="truncated"):
+        DeviceGraph.from_graph(g, d_max=2, lane=1)
+
+
 def test_overflow_retry_is_exact():
     """Tiny capacities force overflow; the driver must still be exact."""
     p = get_pattern("house")

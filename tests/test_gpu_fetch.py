@@ -75,6 +75,24 @@ class TestFusedGatherIntersect:
                                     n, impl="ref")
         np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
 
+    def test_batches_above_smem_rows_split_into_launches(self,
+                                                         monkeypatch):
+        """Ids are scalar-prefetched into SMEM (1 MiB on a v5e): larger
+        batches run as several launches, bit-equal to one."""
+        from repro.kernels import gather_intersect
+        monkeypatch.setattr(gather_intersect, "SMEM_ROWS", 16)
+        n, d, b = 50, 256, 44                 # 44 rows -> 48 -> 3 launches
+        rng = np.random.default_rng(5)
+        adj = _rand_adjacency(rng, n, d)
+        cand = _rand_padded_sets(rng, b, 128, n)
+        ids = rng.integers(0, n + 1, size=b).astype(np.int32)
+        got = ops.fused_gather_intersect(jnp.asarray(cand), jnp.asarray(ids),
+                                         jnp.asarray(adj), n,
+                                         impl="interpret")
+        want = ops.intersect_padded(jnp.asarray(cand),
+                                    jnp.asarray(adj[ids]), n, impl="ref")
+        np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
+
     def test_fallback_impls_match(self):
         """ref/chunked/binary fall back to gather-then-intersect."""
         n, d = 60, 128

@@ -35,6 +35,22 @@ class TestSortedIntersect:
         got = ops.intersect_padded(a, bb, n, impl="interpret")
         np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
 
+    @pytest.mark.parametrize("d,bk", [(256, 128), (2048, 256)])
+    def test_multichunk_tilings_vs_ref(self, d, bk):
+        """Rows spanning several bk chunks on both sides — the tilings the
+        TPU compiler refused before the chunk loops were rewritten — with
+        in-place holes and hole-only chunks on the a side."""
+        rng = np.random.default_rng(d + bk)
+        n = 3 * d
+        a = _rand_padded_sets(rng, 16, d, n)
+        a[::3, ::5] = n                       # in-place holes (INT results)
+        a[1, bk:] = n                         # trailing hole-only chunks
+        bb = _rand_padded_sets(rng, 16, d, n)
+        want = ref.sorted_intersect(jnp.asarray(a), jnp.asarray(bb), n)
+        got = ops.intersect_padded(jnp.asarray(a), jnp.asarray(bb), n,
+                                   impl="interpret", bm=8, bk=bk)
+        np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
+
     @pytest.mark.parametrize("chunk", [32, 128, 200])
     def test_chunked_vs_ref(self, chunk):
         rng = np.random.default_rng(chunk)

@@ -241,36 +241,37 @@ def _expand(env: Dict[Var, jax.Array], valid: jax.Array,
     Both orders are identical (prefix-sum preserves flat order; the argsort
     was stable), so results are bit-equal.
     """
-    B, D = cand.shape
-    n = B * D
-    flat = cand.reshape(n)
-    valid2 = (cand != sentinel) & valid[:, None]
-    fvalid = valid2.reshape(n)
-    parent = jnp.repeat(jnp.arange(B, dtype=jnp.int32), D)
-    if compaction == "sort":
-        order = jnp.argsort(~fvalid, stable=True)    # valid rows first
-        take = order[:cap]
-        new_valid = fvalid[take]
-        parents = parent[take]
-    else:
-        pos = _flat_cumsum(valid2.astype(jnp.int32)) - 1
-        slot = jnp.where(fvalid & (pos < cap), pos, cap)
-        take = jnp.full((cap + 1,), n, jnp.int32)
-        take = take.at[slot].set(jnp.arange(n, dtype=jnp.int32),
-                                 mode="drop")[:cap]
-        new_valid = take < n
-        take = jnp.where(new_valid, take, 0)
-        parents = parent[take]
-    total = jnp.sum(fvalid)
-    overflow = jnp.maximum(total - jnp.sum(new_valid), 0)
-    new_env: Dict[Var, jax.Array] = {}
-    for v, arr in env.items():
-        if v in live:
-            new_env[v] = arr[parents]
-    new_env[target] = jnp.where(new_valid, flat[take], sentinel)
-    if extra_cols:
-        for v, arr in extra_cols.items():
-            new_env[v] = jnp.where(new_valid, arr.reshape(n)[take], 0)
+    with jax.named_scope("enu"):
+        B, D = cand.shape
+        n = B * D
+        flat = cand.reshape(n)
+        valid2 = (cand != sentinel) & valid[:, None]
+        fvalid = valid2.reshape(n)
+        parent = jnp.repeat(jnp.arange(B, dtype=jnp.int32), D)
+        if compaction == "sort":
+            order = jnp.argsort(~fvalid, stable=True)    # valid rows first
+            take = order[:cap]
+            new_valid = fvalid[take]
+            parents = parent[take]
+        else:
+            pos = _flat_cumsum(valid2.astype(jnp.int32)) - 1
+            slot = jnp.where(fvalid & (pos < cap), pos, cap)
+            take = jnp.full((cap + 1,), n, jnp.int32)
+            take = take.at[slot].set(jnp.arange(n, dtype=jnp.int32),
+                                     mode="drop")[:cap]
+            new_valid = take < n
+            take = jnp.where(new_valid, take, 0)
+            parents = parent[take]
+        total = jnp.sum(fvalid)
+        overflow = jnp.maximum(total - jnp.sum(new_valid), 0)
+        new_env: Dict[Var, jax.Array] = {}
+        for v, arr in env.items():
+            if v in live:
+                new_env[v] = arr[parents]
+        new_env[target] = jnp.where(new_valid, flat[take], sentinel)
+        if extra_cols:
+            for v, arr in extra_cols.items():
+                new_env[v] = jnp.where(new_valid, arr.reshape(n)[take], 0)
     return new_env, new_valid, overflow
 
 
@@ -414,30 +415,32 @@ def build_enumerator(plan: Plan,
                     env[ins.target] = ids
                     lazy.add(ins.target)
                 else:
-                    env[ins.target] = fetch(ids)
+                    with jax.named_scope("dbq"):
+                        env[ins.target] = fetch(ids)
             elif ins.op in (INT, TRC):
                 opvars = (list(ins.operands[2:4]) if ins.op == TRC
                           else list(ins.operands))
                 res = None
-                for v in opvars:
-                    if v[0] == "VG":
-                        B = valid.shape[0]
-                        s = jnp.broadcast_to(universe_chunk[None, :],
-                                             (B, universe_chunk.shape[0]))
-                        res = s if res is None else isect(res, s)
-                    elif v in lazy:
-                        lazy.discard(v)          # single-use by construction
-                        # classify_fusable_dbqs only marks non-first
-                        # operands lazy (first operands define the result
-                        # slots and were materialized at their DBQ), so a
-                        # running result always exists here
-                        assert res is not None, v
-                        res = fused(res, env[v])
-                    else:
-                        s = env[v]
-                        res = s if res is None else isect(res, s)
-                if ins.filters:
-                    res = _apply_filters(res, ins.filters, env, sentinel)
+                with jax.named_scope("int"):
+                    for v in opvars:
+                        if v[0] == "VG":
+                            B = valid.shape[0]
+                            s = jnp.broadcast_to(universe_chunk[None, :],
+                                                 (B, universe_chunk.shape[0]))
+                            res = s if res is None else isect(res, s)
+                        elif v in lazy:
+                            lazy.discard(v)      # single-use by construction
+                            # classify_fusable_dbqs only marks non-first
+                            # operands lazy (first operands define the
+                            # result slots and were materialized at their
+                            # DBQ), so a running result always exists here
+                            assert res is not None, v
+                            res = fused(res, env[v])
+                        else:
+                            s = env[v]
+                            res = s if res is None else isect(res, s)
+                    if ins.filters:
+                        res = _apply_filters(res, ins.filters, env, sentinel)
                 env[ins.target] = res
             elif ins.op == ENU:
                 cand = env[ins.operands[0]]

@@ -321,32 +321,35 @@ def build_sbenu_instr_runner(plan: Plan, sentinel: int, caps: Sequence[int],
                 env[ins.target] = jnp.where(valid, starts, sentinel)
             elif ins.op == DBQ:
                 ids = env[ins.operands[0]]
-                op = ins.adj_op
-                env[ins.target] = fetch(ids, ins.adj_type, ins.adj_dir, op,
-                                        env.get(OP_VAR))
+                with jax.named_scope("dbq"):
+                    env[ins.target] = fetch(ids, ins.adj_type, ins.adj_dir,
+                                            ins.adj_op, env.get(OP_VAR))
             elif ins.op == INT:
                 sets = [env[v] for v in ins.operands]
                 flagged = [s for s in sets if isinstance(s, tuple)]
                 plain = [s for s in sets if not isinstance(s, tuple)]
-                if flagged:
-                    # the delta candidate set: flag-aware filtering keeps
-                    # values and signs aligned (Delta-ENU consumes both)
-                    assert len(flagged) == 1
-                    vals, signs = flagged[0]
-                    for other in plain:
-                        vals = isect(vals, other)
-                    if ins.filters:
-                        vals = _apply_filters(vals, ins.filters, env,
-                                              sentinel)
-                    signs = jnp.where(vals != sentinel, signs, 0)
-                    env[ins.target] = (vals, signs)
-                else:
-                    res = plain[0]
-                    for other in plain[1:]:
-                        res = isect(res, other)
-                    if ins.filters:
-                        res = _apply_filters(res, ins.filters, env, sentinel)
-                    env[ins.target] = resort(res)
+                with jax.named_scope("int"):
+                    if flagged:
+                        # the delta candidate set: flag-aware filtering
+                        # keeps values and signs aligned (Delta-ENU
+                        # consumes both)
+                        assert len(flagged) == 1
+                        vals, signs = flagged[0]
+                        for other in plain:
+                            vals = isect(vals, other)
+                        if ins.filters:
+                            vals = _apply_filters(vals, ins.filters, env,
+                                                  sentinel)
+                        signs = jnp.where(vals != sentinel, signs, 0)
+                        env[ins.target] = (vals, signs)
+                    else:
+                        res = plain[0]
+                        for other in plain[1:]:
+                            res = isect(res, other)
+                        if ins.filters:
+                            res = _apply_filters(res, ins.filters, env,
+                                                 sentinel)
+                        env[ins.target] = resort(res)
             elif ins.op in (ENU, DENU):
                 extra = None
                 if ins.op == DENU:
@@ -369,8 +372,9 @@ def build_sbenu_instr_runner(plan: Plan, sentinel: int, caps: Sequence[int],
             elif ins.op == INS:
                 fv = env[ins.operands[0]]
                 rows = env[ins.operands[1]]
-                hit = jnp.any(rows == fv[:, None], axis=1)
-                valid = valid & hit & (fv != sentinel)
+                with jax.named_scope("int"):
+                    hit = jnp.any(rows == fv[:, None], axis=1)
+                    valid = valid & hit & (fv != sentinel)
             elif ins.op == RES:
                 opsign = env[OP_VAR]
                 count_plus = count_plus + jnp.sum(
@@ -416,10 +420,10 @@ def build_sbenu_enumerator(plan: Plan, sentinel: int, caps: Sequence[int],
         # prev/cur stacked per direction: the per-row snapshot selector
         # becomes a single offset gather instead of two gathers + where
         # (XLA CSEs the concats across repeated DBQs and fused plans)
-        stacked = {"out": jnp.concatenate([snap.prev_out, snap.cur_out],
-                                          axis=0),
-                   "in": jnp.concatenate([snap.prev_in, snap.cur_in],
-                                         axis=0)}
+        with jax.named_scope("dbq"):
+            stacked = {di: jnp.concatenate([p, c], axis=0) for di, p, c in
+                       (("out", snap.prev_out, snap.cur_out),
+                        ("in", snap.prev_in, snap.cur_in))}
         prev = {"out": snap.prev_out, "in": snap.prev_in}
         cur = {"out": snap.cur_out, "in": snap.cur_in}
         delta = {"out": (snap.delta_out, snap.delta_out_sign),

@@ -55,6 +55,7 @@ Example (the reference interpreter; every other engine is a drop-in
 
 from __future__ import annotations
 
+import itertools
 from abc import ABC, abstractmethod
 from dataclasses import dataclass, field
 from typing import (Any, Callable, Dict, Iterator, List, Optional, Sequence,
@@ -62,6 +63,7 @@ from typing import (Any, Callable, Dict, Iterator, List, Optional, Sequence,
 
 import numpy as np
 
+from .. import obs
 from ..graph.storage import Graph
 from .instructions import ENU, Plan
 from .pattern import Pattern
@@ -257,6 +259,10 @@ class ExecutorBackend(ABC):
 # --------------------------------------------------------------------------
 
 
+#: keys of ``drive`` calls made outside any keyed span (a census task)
+_drive_calls = itertools.count(1)
+
+
 def drive(backend: ExecutorBackend, plan: Any, source: Any,
           config: ExecutorConfig) -> ExecStats:
     """Run ``plan`` over ``source`` on ``backend`` — exactly.
@@ -267,6 +273,14 @@ def drive(backend: ExecutorBackend, plan: Any, source: Any,
     frontiers) or, once a chunk is a single unsplittable batch, with
     doubled capacities.
     """
+    key = None if obs.current_key() is not None else \
+        ("drive", next(_drive_calls))
+    with obs.span("drive", key=key):
+        return _drive(backend, plan, source, config)
+
+
+def _drive(backend: ExecutorBackend, plan: Any, source: Any,
+           config: ExecutorConfig) -> ExecStats:
     backend.prepare(plan, source, config)
     stats = ExecStats()
     all_matches: List[np.ndarray] = []
@@ -292,6 +306,7 @@ def drive(backend: ExecutorBackend, plan: Any, source: Any,
                     continue
                 res = backend.run_chunk(cids, cvalid, uni, caps)
                 stats.chunks_run += 1
+                obs.count("chunks.run")
                 ok = res.overflow == 0 and res.drops == 0
                 if ok:
                     stats.count += int(res.count)
@@ -309,6 +324,7 @@ def drive(backend: ExecutorBackend, plan: Any, source: Any,
                                             backend.granularity, sentinel)
                 if halves is not None:
                     stats.chunks_split += 1
+                    obs.count("chunks.split")
                     for h_ids, h_valid in halves:
                         work.append((h_ids, h_valid, caps, tries))
                     continue
@@ -317,6 +333,7 @@ def drive(backend: ExecutorBackend, plan: Any, source: Any,
                         f"[{backend.name}] chunk overflowed after "
                         f"{tries} escalations (caps={caps})")
                 stats.chunks_retried += 1
+                obs.count("chunks.retried")
                 new_caps = round_caps(backend.grow_caps(caps)) \
                     if res.overflow else caps
                 work.append((cids, cvalid, new_caps, tries + 1))
@@ -440,6 +457,11 @@ class JaxBackend(ExecutorBackend):
 
     def prepare(self, plan: Plan, source: Graph,
                 config: ExecutorConfig) -> None:
+        with obs.span("prepare"):
+            self._prepare(plan, source, config)
+
+    def _prepare(self, plan: Plan, source: Graph,
+                 config: ExecutorConfig) -> None:
         import jax
         from ..kernels import dispatch
         from .engine_jax import (DeviceGraph, check_jit_supported,
@@ -486,30 +508,37 @@ class JaxBackend(ExecutorBackend):
                                         row_fetch(rows, sentinel),
                                         fused_rows=lanes, **kw)(*args)
 
-            self._runners[key] = self._jit(run)
+            self._runners[key] = obs.build_on_first_call(self._jit(run))
         return self._runners[key]
 
     def run_chunk(self, ids, valid, universe_chunk, caps) -> ChunkResult:
+        with obs.span("chunk"):
+            return self._run_chunk(ids, valid, universe_chunk, caps)
+
+    def _run_chunk(self, ids, valid, universe_chunk, caps) -> ChunkResult:
         import jax.numpy as jnp
-        args = (self.dg.rows, self.dg.lane_rows if self.fused else None,
-                jnp.asarray(ids), jnp.asarray(valid))
-        if universe_chunk is not None:
-            args = args + (jnp.asarray(universe_chunk),)
-        res = self._runner(ids.shape[0], caps)(*args)
-        ov = int(res.overflow)
-        matches = None
-        if self._collect and ov == 0 and res.matches is not None:
-            m = np.asarray(res.matches)
-            matches = m[np.asarray(res.matches_valid)]
-        if ov == 0 and res.level_sizes:
-            # accepted chunks only: aggregate frontier occupancy per ENU
-            # level (benchmarks/roofline.py --fused reads this to model
-            # achieved vs lane-math bytes for the fetch paths)
-            lv = np.asarray([int(s) for s in res.level_sizes], np.int64)
-            self._level_acc = (lv if self._level_acc is None
-                               else self._level_acc + lv)
-        return ChunkResult(count=int(res.count), overflow=ov,
-                           matches=matches)
+        with obs.span("chunk.dispatch"):
+            args = (self.dg.rows, self.dg.lane_rows if self.fused else None,
+                    jnp.asarray(ids), jnp.asarray(valid))
+            if universe_chunk is not None:
+                args = args + (jnp.asarray(universe_chunk),)
+            res = self._runner(ids.shape[0], caps)(*args)
+        with obs.span("chunk.wait"):
+            ov = int(res.overflow)
+        with obs.span("chunk.decode"):
+            matches = None
+            if self._collect and ov == 0 and res.matches is not None:
+                m = np.asarray(res.matches)
+                matches = m[np.asarray(res.matches_valid)]
+            if ov == 0 and res.level_sizes:
+                # accepted chunks only: aggregate frontier occupancy per
+                # ENU level (benchmarks/roofline.py --fused reads this to
+                # model achieved vs lane-math bytes for the fetch paths)
+                lv = np.asarray([int(s) for s in res.level_sizes], np.int64)
+                self._level_acc = (lv if self._level_acc is None
+                                   else self._level_acc + lv)
+            return ChunkResult(count=int(res.count), overflow=ov,
+                               matches=matches)
 
     def finalize(self, stats: ExecStats) -> None:
         stats.extras.update(
@@ -867,6 +896,11 @@ class SBenuJaxBackend(ExecutorBackend):
 
     def prepare(self, plans: Sequence[Plan], source,
                 config: ExecutorConfig) -> None:
+        with obs.span("prepare"):
+            self._prepare(plans, source, config)
+
+    def _prepare(self, plans: Sequence[Plan], source,
+                 config: ExecutorConfig) -> None:
         import jax
         from ..graph.dynamic import DeviceSnapshotStore
         from .engine_sbenu_jax import plan_level_count
@@ -960,30 +994,37 @@ class SBenuJaxBackend(ExecutorBackend):
                 collect_matches=self._collect,
                 intersect_impl=self._intersect,
                 compaction=self._compaction)
-            self._runners[key] = self._jit(run)
+            self._runners[key] = obs.build_on_first_call(self._jit(run))
         return self._runners[key]
 
     def run_chunk(self, ids, valid, universe_chunk, caps) -> ChunkResult:
+        with obs.span("chunk"):
+            return self._run_chunk(ids, valid, caps)
+
+    def _run_chunk(self, ids, valid, caps) -> ChunkResult:
         import jax.numpy as jnp
-        jids, jvalid = jnp.asarray(ids), jnp.asarray(valid)
-        # all ΔP_i plans run in one fused dispatch per chunk
-        res = self._runner(ids.shape[0], tuple(caps))(self.snap, jids,
-                                                      jvalid)
-        ov = int(res.overflow)
+        with obs.span("chunk.dispatch"):
+            jids, jvalid = jnp.asarray(ids), jnp.asarray(valid)
+            # all ΔP_i plans run in one fused dispatch per chunk
+            res = self._runner(ids.shape[0], tuple(caps))(self.snap, jids,
+                                                          jvalid)
+        with obs.span("chunk.wait"):
+            ov = int(res.overflow)
         if ov:
             # discard the whole chunk; the driver re-splits or grows
             return ChunkResult(count=0, overflow=ov)
-        cp, cm = int(res.count_plus), int(res.count_minus)
-        if self._collect and res.matches is not None:
-            mv = np.asarray(res.matches_valid)
-            rows = np.asarray(res.matches)[mv]
-            ops = np.asarray(res.match_ops)[mv]
-            for row, o in zip(rows, ops):
-                (self._plus if o > 0 else self._minus).append(
-                    tuple(int(x) for x in row))
-        self._count_plus += cp
-        self._count_minus += cm
-        return ChunkResult(count=cp + cm)
+        with obs.span("chunk.decode"):
+            cp, cm = int(res.count_plus), int(res.count_minus)
+            if self._collect and res.matches is not None:
+                mv = np.asarray(res.matches_valid)
+                rows = np.asarray(res.matches)[mv]
+                ops = np.asarray(res.match_ops)[mv]
+                for row, o in zip(rows, ops):
+                    (self._plus if o > 0 else self._minus).append(
+                        tuple(int(x) for x in row))
+            self._count_plus += cp
+            self._count_minus += cm
+            return ChunkResult(count=cp + cm)
 
     def finalize(self, stats: ExecStats) -> None:
         from .sbenu import SBenuCounters

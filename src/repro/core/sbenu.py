@@ -25,6 +25,7 @@ from __future__ import annotations
 from dataclasses import dataclass, replace
 from typing import Dict, FrozenSet, List, Optional, Sequence, Set, Tuple
 
+from .. import obs
 from ..graph.dynamic import SnapshotStore, Update
 from ..graph.storage import DiGraph
 from .estimate import DEFAULT_STATS, GraphStats
@@ -505,7 +506,6 @@ def run_timestep(pattern: Pattern, plans: Sequence[Plan],
     """
     from .executor import (ExecutorConfig, SBenuBackend, SBenuDistBackend,
                            SBenuJaxBackend, drive)
-    store.begin_step(batch)
     if backend is None:
         if engine in ("ref", "sbenu"):
             backend = SBenuBackend(pattern, cache_capacity=cache_capacity,
@@ -518,12 +518,18 @@ def run_timestep(pattern: Pattern, plans: Sequence[Plan],
                                        **backend_kwargs)
         else:
             raise ValueError(f"unknown S-BENU engine {engine!r}")
-    st = drive(backend, list(plans), store,
-               ExecutorConfig(batch=chunk, theta=theta,
-                              collect_matches=(collect == "matches")))
-    store.end_step()
-    return (st.extras["delta_plus"], st.extras["delta_minus"],
-            st.extras["counters"])
+    # keyed by the step begin_step opens, so every span and counter of
+    # the step can be grouped by it
+    with obs.span("timestep", key=store.t + 1):
+        store.begin_step(batch)
+        st = drive(backend, list(plans), store,
+                   ExecutorConfig(batch=chunk, theta=theta,
+                                  collect_matches=(collect == "matches")))
+        store.end_step()
+        ctr = st.extras["counters"]
+        obs.count("delta.plus", ctr.matches_plus)
+        obs.count("delta.minus", ctr.matches_minus)
+    return st.extras["delta_plus"], st.extras["delta_minus"], ctr
 
 
 # --------------------------------------------------------------------------

@@ -62,6 +62,7 @@ from typing import Dict, Iterable, List, Optional, Sequence, Set, Tuple
 
 import numpy as np
 
+from .. import obs
 from .storage import DiGraph, pad_rows
 
 Update = Tuple[str, int, int]  # (op, src, dst)
@@ -123,40 +124,41 @@ class SnapshotStore:
         self.delta_out: Dict[int, Dict[int, str]] = {}
         self.delta_in: Dict[int, Dict[int, str]] = {}
         self.t = 0
-        self.total_queries = 0
         # device-resident mirrors notified on end_step (DeviceSnapshotStore)
         self._mirrors: List["DeviceSnapshotStore"] = []
 
     # ------------------------------------------------------------ time steps
     def begin_step(self, batch: Sequence[Update]) -> None:
         """Convert Δo_t into delta adjacency sets (Alg. 4 lines 7-9)."""
-        self.t += 1
-        self.delta_out = {}
-        self.delta_in = {}
-        seen: Set[Tuple[int, int]] = set()
-        for op, a, b in batch:
-            if (a, b) in seen:
-                raise ValueError(f"edge ({a},{b}) appears twice in batch")
-            seen.add((a, b))
-            if op == "+" and self.prev.has_edge(a, b):
-                raise ValueError(f"inserting existing edge ({a},{b})")
-            if op == "-" and not self.prev.has_edge(a, b):
-                raise ValueError(f"deleting missing edge ({a},{b})")
-            self.delta_out.setdefault(a, {})[b] = op
-            self.delta_in.setdefault(b, {})[a] = op
+        with obs.span("store.begin_step"):
+            self.t += 1
+            self.delta_out = {}
+            self.delta_in = {}
+            seen: Set[Tuple[int, int]] = set()
+            for op, a, b in batch:
+                if (a, b) in seen:
+                    raise ValueError(f"edge ({a},{b}) appears twice in batch")
+                seen.add((a, b))
+                if op == "+" and self.prev.has_edge(a, b):
+                    raise ValueError(f"inserting existing edge ({a},{b})")
+                if op == "-" and not self.prev.has_edge(a, b):
+                    raise ValueError(f"deleting missing edge ({a},{b})")
+                self.delta_out.setdefault(a, {})[b] = op
+                self.delta_in.setdefault(b, {})[a] = op
 
     def end_step(self) -> None:
         """Merge deltas into the stored snapshot (Alg. 4 line 21)."""
-        for a, dd in self.delta_out.items():
-            for b, op in dd.items():
-                if op == "+":
-                    self.prev.add_edge(a, b)
-                else:
-                    self.prev.remove_edge(a, b)
-        for m in self._mirrors:
-            m.on_host_end_step()
-        self.delta_out = {}
-        self.delta_in = {}
+        with obs.span("store.end_step"):
+            for a, dd in self.delta_out.items():
+                for b, op in dd.items():
+                    if op == "+":
+                        self.prev.add_edge(a, b)
+                    else:
+                        self.prev.remove_edge(a, b)
+            for m in self._mirrors:
+                m.on_host_end_step()
+            self.delta_out = {}
+            self.delta_in = {}
 
     # --------------------------------------------------------------- queries
     def start_vertices(self) -> List[int]:
@@ -171,7 +173,6 @@ class SnapshotStore:
     def get_adj(self, v: int, type_: str, direction: str,
                 op: str) -> frozenset:
         """Γ^{type,direction}_{G'_?}(v); ``?`` = t if op=='+', t-1 if op=='-'."""
-        self.total_queries += 1
         prev = self.prev.out[v] if direction == "out" else self.prev.inn[v]
         dd = (self.delta_out if direction == "out" else self.delta_in
               ).get(v, {})
@@ -346,24 +347,29 @@ class DeviceSnapshotStore:
         # host mode: di -> (touched ids int64[K], merged rows int32[K, D])
         self._cur_host: Dict[str, Tuple[np.ndarray, np.ndarray]] = {}
         self._pending_t: Optional[int] = None
+        # this mirror's rebuilds; obs counter snapshot.rebuilds counts
+        # them over the process
         self.rebuilds = 0
+        # (prev, tids, delta) shapes derive has been called with
+        self._derive_shapes: Set[Tuple[Tuple[int, ...], ...]] = set()
 
         def derive(prev, tids, dvals, dsigns):
             """cur block from prev + the touched rows' delta (tids are
             sentinel-padded: padding rewrites the sentinel row with
             itself). Merged rows stay sorted with tail holes, so the
             engines' binary-search intersect b-side invariant holds."""
-            d = prev.shape[1]
-            rows = prev[tids]                       # [K, D]
-            dv = dvals[tids]                        # [K, Dd]
-            ds = dsigns[tids]
-            deleted = jnp.where(ds < 0, dv, self.n)
-            hit = jnp.any(rows[:, :, None] == deleted[:, None, :], axis=2)
-            unalt = jnp.where(hit, self.n, rows)
-            plus = jnp.where(ds > 0, dv, self.n)
-            merged = jnp.sort(jnp.concatenate([unalt, plus], axis=1),
-                              axis=1)[:, :d]        # fits: width guard
-            return prev.at[tids].set(merged)
+            with jax.named_scope("derive"):
+                d = prev.shape[1]
+                rows = prev[tids]                       # [K, D]
+                dv = dvals[tids]                        # [K, Dd]
+                ds = dsigns[tids]
+                deleted = jnp.where(ds < 0, dv, self.n)
+                hit = jnp.any(rows[:, :, None] == deleted[:, None, :], axis=2)
+                unalt = jnp.where(hit, self.n, rows)
+                plus = jnp.where(ds > 0, dv, self.n)
+                merged = jnp.sort(jnp.concatenate([unalt, plus], axis=1),
+                                  axis=1)[:, :d]        # fits: width guard
+                return prev.at[tids].set(merged)
 
         self._derive_fn = derive
         self._derive = jax.jit(derive)
@@ -396,7 +402,8 @@ class DeviceSnapshotStore:
         :class:`HostRowStore` shards (one shard transient at a time)."""
         from .hoststore import HostRowStore
         self.rebuilds += 1
-        n, jnp = self.n, self._jnp
+        obs.count("snapshot.rebuilds")
+        n = self.n
         self._prev = {}
         for di, sets, delta in (("out", self.host.prev.out,
                                  self.host.delta_out),
@@ -415,13 +422,20 @@ class DeviceSnapshotStore:
                 for v, s in enumerate(sets):
                     a = sorted(s)
                     rows[v, :len(a)] = a
-                self._prev[di] = self._place(rows)
+                with obs.span("snapshot.place"):
+                    obs.count("snapshot.h2d_bytes", rows.nbytes)
+                    self._prev[di] = self._place(rows)
             self._d[di] = d
 
     def _delta_buffers(self, delta: Dict[int, Dict[int, str]]
                        ) -> Tuple[np.ndarray, np.ndarray, int]:
         """Vectorized COO scatter of one direction's delta dicts into
         fresh value/sign buffers."""
+        with obs.span("snapshot.delta_buffers"):
+            return self._delta_buffers_body(delta)
+
+    def _delta_buffers_body(self, delta: Dict[int, Dict[int, str]]
+                            ) -> Tuple[np.ndarray, np.ndarray, int]:
         n = self.n
         items = [(v, w, 1 if op == "+" else -1)
                  for v, ops in delta.items() for w, op in ops.items()]
@@ -468,16 +482,18 @@ class DeviceSnapshotStore:
         of G'_t outgrowing the pinned width forces a wider rebuild
         (deletes only shrink rows)."""
         st = self.host
-        if self._prev is not None:
-            for di, sets, delta in (("out", st.prev.out, st.delta_out),
-                                    ("in", st.prev.inn, st.delta_in)):
-                if any(len(sets[v]) + sum(1 for op in ops.values()
-                                          if op == "+") > self._d[di]
-                       for v, ops in delta.items()):
-                    self._prev = None
-                    break
-        if self._prev is None:
-            self._rebuild_prev()
+        with obs.span("snapshot.fit"):
+            if self._prev is not None:
+                for di, sets, delta in (("out", st.prev.out, st.delta_out),
+                                        ("in", st.prev.inn, st.delta_in)):
+                    if any(len(sets[v]) + sum(1 for op in ops.values()
+                                              if op == "+") > self._d[di]
+                           for v, ops in delta.items()):
+                        self._prev = None
+                        break
+            if self._prev is None:
+                with obs.span("snapshot.rebuild"):
+                    self._rebuild_prev()
 
     def _ensure_step_cur_host(self) -> None:
         """Host mode: derive (and cache) both directions' merged touched
@@ -521,15 +537,28 @@ class DeviceSnapshotStore:
         blocks: Dict[str, object] = {}
         for di, delta in (("out", st.delta_out), ("in", st.delta_in)):
             vals, signs, _ = self._delta_buffers(delta)
-            jvals, jsigns = self._place(vals), self._place(signs)
             # touched ids, sentinel-padded to a power of two so steps with
             # similar churn share one compiled derive shape
             touched = sorted(delta)
             k = 1 << max(len(touched) - 1, 0).bit_length()
             tids = np.full(max(k, 1), self.n, np.int32)
             tids[:len(touched)] = touched
-            cur = self._derive(self._prev[di], jnp.asarray(tids), jvals,
-                               jsigns)
+            with obs.span("snapshot.place"):
+                obs.count("snapshot.h2d_bytes",
+                          vals.nbytes + signs.nbytes + tids.nbytes)
+                jvals, jsigns = self._place(vals), self._place(signs)
+                jtids = jnp.asarray(tids)
+            prev = self._prev[di]
+            shapes = (prev.shape, tids.shape, vals.shape)
+            with obs.span("snapshot.derive"):
+                if shapes in self._derive_shapes:
+                    cur = self._derive(prev, jtids, jvals, jsigns)
+                else:
+                    # a new shape: tracing, compiling, first dispatch
+                    self._derive_shapes.add(shapes)
+                    with obs.span("jit.build"):
+                        obs.count("jit.builds")
+                        cur = self._derive(prev, jtids, jvals, jsigns)
             self._cur[di] = cur
             blocks[f"prev_{di}"] = self._prev[di]
             blocks[f"cur_{di}"] = cur

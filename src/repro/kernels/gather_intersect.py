@@ -147,4 +147,5 @@ def gather_intersect_pallas(ids: jax.Array, cand: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Dc), cand.dtype),
         interpret=interpret,
+        name="gather_intersect_pallas",
     )(ids, cand, lanes)

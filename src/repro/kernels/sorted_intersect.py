@@ -122,4 +122,5 @@ def sorted_intersect_pallas(a: jax.Array, b: jax.Array, sentinel: int,
         out_specs=pl.BlockSpec((bm, D), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, D), a.dtype),
         interpret=interpret,
+        name="sorted_intersect_pallas",
     )(a, b)

@@ -36,6 +36,13 @@ vectorized delta-frontier engine over the six-block device snapshot;
 (``--devices N`` forces an N-way host mesh) with typed DBQs served by
 request/response all_to_all — ``--hot`` rows replicated, ``--rebalance``
 striping every delta frontier round-robin across the mesh.
+
+Every run ends with the engine's own spans and counters (``repro.obs``):
+each span's self time (its duration less its child spans') summed over
+the run, e.g. ``snapshot.delta_buffers`` and ``chunk.wait`` per step,
+then every counter (``snapshot.h2d_bytes``, ``chunks.split``,
+``jit.builds``, ``delta.plus`` ...). The first step's ``jit.build`` time
+is compilation.
 """
 
 from __future__ import annotations
@@ -43,6 +50,17 @@ from __future__ import annotations
 import argparse
 import os
 import time
+
+
+def _print_obs() -> None:
+    """Self time per span and every counter the run recorded."""
+    from .. import obs
+    print("\nspan self time (s):")
+    for name, sec in sorted(obs.self_times().items(), key=lambda kv: -kv[1]):
+        print(f"  {name:<24} {sec:10.4f}")
+    print("counters:")
+    for name, n in sorted(obs.counters().items()):
+        print(f"  {name:<24} {n}")
 
 
 def _run_continuous(args) -> None:
@@ -102,6 +120,7 @@ def _run_continuous(args) -> None:
         print(f"mesh               : {len(jax.devices())} devices "
               f"(hot {args.hot} rows replicated, "
               f"rebalance {'on' if args.rebalance else 'off'})")
+    _print_obs()
 
 
 def main():
@@ -229,6 +248,7 @@ def main():
         print(f"fused fetch        : "
               f"{'on' if st.extras['fused_fetch'] else 'off'}")
         print(f"frontier rows/level: {lv.tolist()}")
+    _print_obs()
 
 
 if __name__ == "__main__":

@@ -57,6 +57,7 @@ Example (two time steps; ``get_adj`` serves both snapshots)::
 
 from __future__ import annotations
 
+import itertools
 from dataclasses import dataclass
 from typing import Dict, Iterable, List, Optional, Sequence, Set, Tuple
 
@@ -288,17 +289,20 @@ class DeviceSnapshotStore:
     """Device-resident dual-snapshot row store (the streaming fast path).
 
     Keeps the ``prev`` blocks resident on device across time steps and
-    advances them incrementally, so per-step host work is O(|ΔE|) instead
-    of an O(N) Python rebuild:
+    advances them incrementally, so per-step host work and upload are
+    O(|ΔE|) instead of an O(N) Python rebuild:
 
-    * :meth:`step_snapshot` (store begun): scatter the update batch into
-      the delta value/sign buffers (vectorized COO build), then derive
-      ``G'_t`` **on device, touched rows only**: gather the |ΔV| touched
-      prev rows, mask deleted entries, merge the inserted delta values
-      (concat + row sort + slice back to width D — the merged row fits by
-      the width guard), and scatter them into a copy of the prev block.
-      Per-step device cost is O(|ΔV|·D) plus one O(N·D) memcpy, not a
-      full-graph masked sort.
+    * :meth:`step_snapshot` (store begun): pack the update batch into
+      touched rows (vectorized COO build: ids ``[K]``, values and signs
+      ``[K, Dd]``, K the touched count padded to a power of two), send
+      only those up, then in one device program per direction derive
+      ``G'_t`` **touched rows only** — gather the K prev rows, mask
+      deleted entries, merge the inserted delta values (concat + row sort
+      + slice back to width D — the merged row fits by the width guard),
+      and scatter them into a copy of the prev block — and scatter the
+      touched rows into fresh dense ``delta`` / ``delta_sign`` blocks.
+      Per-step device cost is O(|ΔV|·D) plus O(N·D) of copies and fills,
+      not a full-graph masked sort.
     * end_step (via the :class:`SnapshotStore` mirror hook): the merged
       snapshot IS the cur block, so promotion is free buffer adoption
       (``prev <- cur``). Width overflow drops the mirror; the next step
@@ -350,26 +354,30 @@ class DeviceSnapshotStore:
         # this mirror's rebuilds; obs counter snapshot.rebuilds counts
         # them over the process
         self.rebuilds = 0
-        # (prev, tids, delta) shapes derive has been called with
+        # (prev, touched-row values) shapes derive has been called with
         self._derive_shapes: Set[Tuple[Tuple[int, ...], ...]] = set()
 
-        def derive(prev, tids, dvals, dsigns):
-            """cur block from prev + the touched rows' delta (tids are
-            sentinel-padded: padding rewrites the sentinel row with
-            itself). Merged rows stay sorted with tail holes, so the
-            engines' binary-search intersect b-side invariant holds."""
+        def derive(prev, tids, kvals, ksigns):
+            """``(cur, delta, delta_sign)`` blocks from prev + the touched
+            rows' delta (``tids [K]`` sentinel-padded, ``kvals`` /
+            ``ksigns [K, Dd]`` holes ``n`` / 0: padding rewrites the
+            sentinel row with itself). Merged rows stay sorted with tail
+            holes, so the engines' binary-search intersect b-side
+            invariant holds."""
             with jax.named_scope("derive"):
+                n = self.n
                 d = prev.shape[1]
                 rows = prev[tids]                       # [K, D]
-                dv = dvals[tids]                        # [K, Dd]
-                ds = dsigns[tids]
-                deleted = jnp.where(ds < 0, dv, self.n)
+                deleted = jnp.where(ksigns < 0, kvals, n)
                 hit = jnp.any(rows[:, :, None] == deleted[:, None, :], axis=2)
-                unalt = jnp.where(hit, self.n, rows)
-                plus = jnp.where(ds > 0, dv, self.n)
+                unalt = jnp.where(hit, n, rows)
+                plus = jnp.where(ksigns > 0, kvals, n)
                 merged = jnp.sort(jnp.concatenate([unalt, plus], axis=1),
                                   axis=1)[:, :d]        # fits: width guard
-                return prev.at[tids].set(merged)
+                dense = (prev.shape[0], kvals.shape[1])
+                return (prev.at[tids].set(merged),
+                        jnp.full(dense, n, jnp.int32).at[tids].set(kvals),
+                        jnp.zeros(dense, jnp.int32).at[tids].set(ksigns))
 
         self._derive_fn = derive
         self._derive = jax.jit(derive)
@@ -378,6 +386,12 @@ class DeviceSnapshotStore:
     def _place(self, arr: np.ndarray):
         """Device placement of one block (subclass hook: the mesh-sharded
         store device_puts with a row-partitioned NamedSharding here)."""
+        return self._jnp.asarray(arr)
+
+    def _place_touched(self, arr: np.ndarray):
+        """Device placement of one step's touched-row array (subclass
+        hook: the mesh-sharded store replicates it, as K need not divide
+        by the mesh)."""
         return self._jnp.asarray(arr)
 
     @classmethod
@@ -427,34 +441,44 @@ class DeviceSnapshotStore:
                     self._prev[di] = self._place(rows)
             self._d[di] = d
 
-    def _delta_buffers(self, delta: Dict[int, Dict[int, str]]
-                       ) -> Tuple[np.ndarray, np.ndarray, int]:
-        """Vectorized COO scatter of one direction's delta dicts into
-        fresh value/sign buffers."""
+    def _delta_rows(self) -> Dict[str, Tuple[np.ndarray, np.ndarray,
+                                             np.ndarray]]:
+        """The begun step's delta as touched rows, per direction (COO
+        build in one pass over ΔΓ_out: ΔΓ_in holds the same edges
+        reversed). O(|ΔE|) host work, whatever N."""
         with obs.span("snapshot.delta_buffers"):
-            return self._delta_buffers_body(delta)
+            items = [(v, w, 1 if op == "+" else -1)
+                     for v, ops in self.host.delta_out.items()
+                     for w, op in ops.items()]
+            e = np.fromiter(itertools.chain.from_iterable(items), np.int64,
+                            3 * len(items)).reshape(-1, 3)
+            return {"out": self._touched_rows(e[:, 0], e[:, 1], e[:, 2]),
+                    "in": self._touched_rows(e[:, 1], e[:, 0], e[:, 2])}
 
-    def _delta_buffers_body(self, delta: Dict[int, Dict[int, str]]
-                            ) -> Tuple[np.ndarray, np.ndarray, int]:
+    def _touched_rows(self, src: np.ndarray, dst: np.ndarray,
+                      sign: np.ndarray
+                      ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """``(tids int32[K], vals int32[K, Dd], signs int32[K, Dd])`` of
+        the edges ``src -> dst``: ids ascending then sentinel-padded to a
+        power of two K, so steps with similar churn share one compiled
+        derive shape; each row's values ascending, value holes ``n``,
+        sign holes 0."""
         n = self.n
-        items = [(v, w, 1 if op == "+" else -1)
-                 for v, ops in delta.items() for w, op in ops.items()]
-        if not items:
-            dd = self._round(self.delta_d_min)
-            return (np.full((self._rows_total, dd), n, np.int32),
-                    np.zeros((self._rows_total, dd), np.int32), 0)
-        arr = np.asarray(items, np.int64)
-        arr = arr[np.lexsort((arr[:, 1], arr[:, 0]))]
-        src = arr[:, 0]
-        gstart = np.flatnonzero(np.r_[True, src[1:] != src[:-1]])
-        counts = np.diff(np.r_[gstart, len(src)])
-        pos = np.arange(len(src)) - np.repeat(gstart, counts)
-        dd = self._round(max(int(counts.max()), self.delta_d_min))
-        vals = np.full((self._rows_total, dd), n, np.int32)
-        signs = np.zeros((self._rows_total, dd), np.int32)
-        vals[src, pos] = arr[:, 1]
-        signs[src, pos] = arr[:, 2]
-        return vals, signs, int(counts.max())
+        order = np.lexsort((dst, src))
+        src, dst, sign = src[order], dst[order], sign[order]
+        gstart = np.flatnonzero(np.diff(src, prepend=-1))
+        counts = np.diff(gstart, append=len(src))
+        row = np.repeat(np.arange(len(gstart)), counts)
+        pos = np.arange(len(src)) - gstart[row]
+        k = 1 << max(len(gstart) - 1, 0).bit_length()
+        dd = self._round(max(int(counts.max(initial=0)), self.delta_d_min))
+        tids = np.full(k, n, np.int32)
+        tids[:len(gstart)] = src[gstart]
+        vals = np.full((k, dd), n, np.int32)
+        signs = np.zeros((k, dd), np.int32)
+        vals[row, pos] = dst
+        signs[row, pos] = sign
+        return tids, vals, signs
 
     def _derive_host(self, store, delta: Dict[int, Dict[int, str]]
                      ) -> Tuple[np.ndarray, np.ndarray]:
@@ -519,8 +543,11 @@ class DeviceSnapshotStore:
             # bounded-device path serves rows via row_source() instead)
             self._ensure_step_cur_host()
             blocks_h: Dict[str, np.ndarray] = {}
-            for di, delta in (("out", st.delta_out), ("in", st.delta_in)):
-                vals, signs, _ = self._delta_buffers(delta)
+            for di, (rids, kvals, ksigns) in self._delta_rows().items():
+                dense = (self._rows_total, kvals.shape[1])
+                vals = np.full(dense, self.n, np.int32)
+                signs = np.zeros(dense, np.int32)
+                vals[rids], signs[rids] = kvals, ksigns
                 hs = self._prev[di]
                 tids, merged = self._cur_host[di]
                 prev_full = hs.to_rows()
@@ -533,32 +560,23 @@ class DeviceSnapshotStore:
                 blocks_h[f"delta_{di}_sign"] = signs
             return DeviceSnapshot(n=self.n, **blocks_h)
         self._ensure_prev_fits()
-        jnp = self._jnp
         blocks: Dict[str, object] = {}
-        for di, delta in (("out", st.delta_out), ("in", st.delta_in)):
-            vals, signs, _ = self._delta_buffers(delta)
-            # touched ids, sentinel-padded to a power of two so steps with
-            # similar churn share one compiled derive shape
-            touched = sorted(delta)
-            k = 1 << max(len(touched) - 1, 0).bit_length()
-            tids = np.full(max(k, 1), self.n, np.int32)
-            tids[:len(touched)] = touched
+        for di, touched in self._delta_rows().items():
             with obs.span("snapshot.place"):
                 obs.count("snapshot.h2d_bytes",
-                          vals.nbytes + signs.nbytes + tids.nbytes)
-                jvals, jsigns = self._place(vals), self._place(signs)
-                jtids = jnp.asarray(tids)
+                          sum(a.nbytes for a in touched))
+                args = [self._place_touched(a) for a in touched]
             prev = self._prev[di]
-            shapes = (prev.shape, tids.shape, vals.shape)
+            shapes = (prev.shape, touched[1].shape)
             with obs.span("snapshot.derive"):
                 if shapes in self._derive_shapes:
-                    cur = self._derive(prev, jtids, jvals, jsigns)
+                    cur, jvals, jsigns = self._derive(prev, *args)
                 else:
                     # a new shape: tracing, compiling, first dispatch
                     self._derive_shapes.add(shapes)
                     with obs.span("jit.build"):
                         obs.count("jit.builds")
-                        cur = self._derive(prev, jtids, jvals, jsigns)
+                        cur, jvals, jsigns = self._derive(prev, *args)
             self._cur[di] = cur
             blocks[f"prev_{di}"] = self._prev[di]
             blocks[f"cur_{di}"] = cur
@@ -698,8 +716,9 @@ class ShardedDeviceSnapshotStore(DeviceSnapshotStore):
         self.hot = min(int(hot), store.n)
         self._jax = jax
         self._sh2d = NamedSharding(mesh, PartitionSpec(axis, None))
-        self._rep2d = NamedSharding(mesh, PartitionSpec(None, None))
+        self._rep = NamedSharding(mesh, PartitionSpec())
         # re-jit the shared derive with the row-partitioned output layout
+        # (cur and the dense delta blocks alike)
         self._derive = jax.jit(self._derive_fn, out_shardings=self._sh2d)
         self.params = (lane, d_min, delta_d_min, "sharded", self.S,
                        axis, self.hot)
@@ -719,6 +738,9 @@ class ShardedDeviceSnapshotStore(DeviceSnapshotStore):
 
     def _place(self, arr: np.ndarray):
         return self._jax.device_put(np.asarray(arr), self._sh2d)
+
+    def _place_touched(self, arr: np.ndarray):
+        return self._jax.device_put(arr, self._rep)
 
     def step_sharded(self) -> Tuple[Dict[str, object], Dict[str, object],
                                     SnapshotShardSpec]:
@@ -744,7 +766,7 @@ class ShardedDeviceSnapshotStore(DeviceSnapshotStore):
                                 axis=1), self._sh2d),
         }
         lo = self.n - self.hot
-        hot_blocks = {k: self._jax.device_put(v[lo:self.n + 1], self._rep2d)
+        hot_blocks = {k: self._jax.device_put(v[lo:self.n + 1], self._rep)
                       for k, v in blocks.items()}
         spec = SnapshotShardSpec(n=self.n, n_shards=self.S,
                                  rows_per_shard=self.rows_per_shard,

@@ -151,6 +151,7 @@ class DiGraph:
         self.n = n
         self.out: List[set] = [set() for _ in range(n)]
         self.inn: List[set] = [set() for _ in range(n)]
+        self._m = 0       # edge count, kept by add_edge / remove_edge
 
     @staticmethod
     def from_edges(n: int, edges: Iterable[Edge]) -> "DiGraph":
@@ -160,14 +161,18 @@ class DiGraph:
         return g
 
     def add_edge(self, a: int, b: int) -> None:
-        if a == b:
+        if a == b or b in self.out[a]:
             return
         self.out[a].add(b)
         self.inn[b].add(a)
+        self._m += 1
 
     def remove_edge(self, a: int, b: int) -> None:
-        self.out[a].discard(b)
-        self.inn[b].discard(a)
+        if b not in self.out[a]:
+            return
+        self.out[a].remove(b)
+        self.inn[b].remove(a)
+        self._m -= 1
 
     def has_edge(self, a: int, b: int) -> bool:
         return b in self.out[a]
@@ -176,11 +181,13 @@ class DiGraph:
         g = DiGraph(self.n)
         g.out = [set(s) for s in self.out]
         g.inn = [set(s) for s in self.inn]
+        g._m = self._m
         return g
 
     @property
     def m(self) -> int:
-        return sum(len(s) for s in self.out)
+        """Edge count in O(1): the streaming backends read it every step."""
+        return self._m
 
     def edges(self) -> Iterable[Edge]:
         for v in range(self.n):

@@ -7,10 +7,12 @@ import warnings
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.core.estimate import GraphStats
 from repro.core.pattern import get_pattern
 from repro.core.sbenu import generate_best_sbenu_plans, snapshot_diff_oracle
-from repro.graph.dynamic import DeviceSnapshotStore, SnapshotStore
+from repro.graph.dynamic import (DeviceSnapshotStore, SnapshotStore,
+                                 stream_width_floors)
 from repro.graph.generate import edge_stream
 from repro.graph.storage import DiGraph, Graph
 
@@ -51,6 +53,47 @@ def test_digraph_padded_adjacency_directions():
     inn = g.padded_adjacency("in")
     assert {int(x) for x in out[0] if x != 4} == {1, 2}
     assert {int(x) for x in inn[0] if x != 4} == {3}
+
+
+def _small_digraph():
+    return DiGraph.from_edges(4, [(0, 1), (0, 2), (1, 2), (3, 1)])
+
+
+def _dup_and_loop():
+    return DiGraph.from_edges(5, [(0, 1), (0, 1), (2, 2), (3, 4), (4, 3)])
+
+
+def _add_existing():
+    g = _small_digraph()
+    g.add_edge(0, 1)
+    g.add_edge(1, 2)
+    return g
+
+
+def _remove_missing():
+    g = _small_digraph()
+    g.remove_edge(2, 0)                 # (0, 2) is there, (2, 0) is not
+    g.remove_edge(0, 2)
+    g.remove_edge(0, 2)
+    return g
+
+
+def _mutated_copy():
+    g = _small_digraph()
+    c = g.copy()
+    c.add_edge(3, 0)
+    c.remove_edge(0, 1)
+    c.remove_edge(1, 2)
+    assert g.m == 4 == sum(len(s) for s in g.out)   # original untouched
+    assert g.has_edge(0, 1) and not g.has_edge(3, 0)
+    return c
+
+
+@pytest.mark.parametrize("build", [_dup_and_loop, _add_existing,
+                                   _remove_missing, _mutated_copy])
+def test_digraph_edge_count_is_kept(build):
+    g = build()
+    assert g.m == sum(len(s) for s in g.out) == sum(len(s) for s in g.inn)
 
 
 # --------------------------------------------------------------------------
@@ -133,6 +176,65 @@ def test_device_snapshot_store_invalidates_when_bypassed():
             _row_set(np.asarray(want.cur_out), v, n)
     store.end_step()
     assert ds.rebuilds >= 2
+
+
+_BLOCKS = ("prev_out", "prev_in", "cur_out", "cur_in", "delta_out",
+           "delta_out_sign", "delta_in", "delta_in_sign")
+
+
+@pytest.mark.parametrize("seed", [3, 11, 23])
+def test_device_snapshot_store_blocks_equal_host_build_bitwise(seed):
+    """The mirror sends only touched rows up and builds the dense delta
+    blocks on device; every block must still equal the host build bit for
+    bit, through a step with no deltas at all and one with a vertex
+    holding a full delta row (Dd updates)."""
+    g0, batches = edge_stream(n=40, m_init=150, steps=3, batch=20,
+                              seed=seed)
+    store = SnapshotStore(g0)
+    ds = DeviceSnapshotStore(store, d_min=40)       # no width rebuilds
+
+    def check(batch):
+        store.begin_step(batch)
+        got = ds.step_snapshot()
+        want = store.device_snapshot(d_min=40)
+        for name in _BLOCKS:
+            g, w = np.asarray(getattr(got, name)), getattr(want, name)
+            assert g.dtype == w.dtype and np.array_equal(g, w), name
+        store.end_step()
+
+    for batch in batches:
+        check(batch)
+    check([])                                       # no in- or out-deltas
+    v, dd = 0, 8
+    outs = sorted(store.prev.out[v])[:dd // 2]
+    news = [w for w in range(1, store.n) if w not in store.prev.out[v]]
+    full = [("-", v, w) for w in outs] + \
+        [("+", v, w) for w in news[:dd - len(outs)]]
+    check(full)
+    assert ds.rebuilds == 1
+
+
+def test_snapshot_upload_does_not_grow_with_n():
+    """After the first (rebuild) step, the bytes a step sends up depend on
+    the batch alone: the same batch over 1,000 and 100,000 vertices."""
+    g0, batches = edge_stream(n=1000, m_init=4000, steps=3, batch=60,
+                              seed=5)
+    floors = stream_width_floors(g0, batches)
+    sent = {}
+    for n in (1000, 100_000):
+        store = SnapshotStore(DiGraph.from_edges(n, g0.edges()))
+        ds = DeviceSnapshotStore(store, d_min=floors[0],
+                                 delta_d_min=floors[1])
+        for t, batch in enumerate(batches):
+            with obs.span("timestep", key=("h2d", n, t)):
+                store.begin_step(batch)
+                ds.step_snapshot()
+                store.end_step()
+            sent[n, t] = obs.counters(key=("h2d", n, t))["snapshot.h2d_bytes"]
+        assert ds.rebuilds == 1
+    assert sent[100_000, 0] > sent[1000, 0]         # the rebuild is O(N)
+    for t in (1, 2):
+        assert sent[100_000, t] == sent[1000, t]
 
 
 # --------------------------------------------------------------------------

@@ -1,22 +1,32 @@
-"""Census cells: time-bounded census tasks over a graph held on the chip.
+"""Census cells: time-bounded census tasks over a graph held on the chips.
 
-Set-up generates the graph from the seed, builds the ``jax-gpu`` backend
-and prepares it once, which puts the adjacency table and its lane-row
-copy in HBM (B-BENU's graph database), then warms the one chunk shape the
-window uses. The window runs tasks - fixed-size blocks of start vertices
-in a seeded random order over all of V, B-BENU's local-search task queue
-- each through the Executor's driver (``drive``), until ``seconds`` have
-passed, and stops at the first task boundary after that. A window that
-runs out of tasks starts the same order again, so it always lasts
-``seconds``. Every task that ran is then checked against the plain
-reference (reference.py).
+The configuration names the engine (``engine``: ``jax-gpu``, the
+default, or ``dist``) and the chips that share the rows (``shards``,
+default 1; the cell's ``chips`` equals it), and may pass the engine's
+own options (``engine_args``, such as ``dist``'s ``hot`` rows). What the
+census needs of each engine sits in :data:`ENGINES`, so a census cell on
+either engine, on one chip or four, is added with files and entries
+alone.
+
+Set-up generates the graph from the seed, builds the engine's backend
+and prepares it once, which puts the graph in HBM (B-BENU's graph
+database: ``jax-gpu``'s row table and its lane-row copy on one chip, or
+``dist``'s rows sharded over the mesh with the hot rows on every chip),
+then warms the one chunk shape the window uses. The window runs tasks -
+fixed-size blocks of start vertices in a seeded random order over all of
+V, B-BENU's local-search task queue - each through the Executor's driver
+(``drive``), until ``seconds`` have passed, and stops at the first task
+boundary after that. A window that runs out of tasks starts the same
+order again, so it always lasts ``seconds``. Every task that ran is then
+checked against the plain reference (reference.py).
 """
 
 from __future__ import annotations
 
 import itertools
 import time
-from typing import Dict
+from dataclasses import dataclass
+from typing import Any, Callable, Dict, Tuple
 
 import numpy as np
 
@@ -29,18 +39,58 @@ from tracing import Spans
 PATTERN_K = {"triangle": 3, "q3": 4}
 
 
-def _task_backend(spans: Spans):
-    from repro.core.executor import JaxGpuBackend
+@dataclass(frozen=True)
+class Engine:
+    """What the census needs of one engine's backend."""
 
-    class TaskBackend(JaxGpuBackend):
-        """``jax-gpu`` prepared once; ``drive`` then runs one task (the
-        start vertices set in ``task``) per call."""
+    backend: str                       # class in repro.core.executor
+    mesh: bool                         # takes a mesh over the cell's chips
+    arrays: Callable[[Any], tuple]     # device arrays that hold the graph
+    width: Callable[[Any], int]        # row width (lanes)
 
+
+ENGINES = {
+    "jax-gpu": Engine("JaxGpuBackend", False,
+                      lambda b: (b.dg.rows, b.dg.lane_rows),
+                      lambda b: b.dg.d),
+    "dist": Engine("DistBackend", True,
+                   lambda b: (b.shards, b.hot_rows),
+                   lambda b: b.spec.d),
+}
+
+
+def layout(cfg: Dict) -> Tuple[str, int]:
+    """The engine the configuration names and the chips that share its
+    rows: ``jax-gpu`` on one chip where it names neither."""
+    return cfg.get("engine", "jax-gpu"), cfg.get("shards", 1)
+
+
+def task_backend(cfg: Dict, spans: Spans):
+    """The configuration's engine over its first ``shards`` chips, prepared
+    once; ``drive`` then runs one task (the start vertices set in
+    ``task``) per call. Returns the backend and its :class:`Engine`."""
+    import jax
+    from repro.core import executor
+    from repro.core.engine_dist import enumeration_mesh
+
+    name, shards = layout(cfg)
+    engine = ENGINES[name]
+    devices = jax.devices()[:shards]
+    if len(devices) != shards or (shards > 1 and not engine.mesh):
+        raise ValueError(f"{engine.backend} cannot hold the rows on "
+                         f"{shards} chips ({len(jax.devices())} present)")
+    kwargs = dict(cfg.get("engine_args", {}))
+    if engine.mesh:
+        kwargs["mesh"] = enumeration_mesh(devices=devices)
+
+    class TaskBackend(getattr(executor, engine.backend)):
         task = None
+        _prepared = False
 
         def prepare(self, plan, source, config):
-            if getattr(self, "dg", None) is None:
+            if not self._prepared:
                 super().prepare(plan, source, config)
+                self._prepared = True
 
         def start_batches(self, config):
             yield self.task
@@ -49,7 +99,15 @@ def _task_backend(spans: Spans):
             with spans.span("chunk"):
                 return super().run_chunk(ids, valid, universe_chunk, caps)
 
-    return TaskBackend()
+    return TaskBackend(**kwargs), engine
+
+
+def enu_rows(backend) -> int:
+    """Frontier rows the ENU levels produced over the backend's accepted
+    chunks so far: its level sizes (``[levels]`` on one chip, ``[levels,
+    shards]`` on a mesh; None before the first chunk), summed."""
+    acc = backend._level_acc
+    return 0 if acc is None else int(acc.sum())
 
 
 def run_cell(cfg: Dict, mix: Dict, seed: int, seconds: float,
@@ -76,15 +134,20 @@ def run_cell(cfg: Dict, mix: Dict, seed: int, seconds: float,
     B = mix["task_size"]
     config = ExecutorConfig(batch=B, caps=tuple(mix["caps"]))
     tasks = graphgen.task_order(seed, n, B)
-    backend = _task_backend(spans)
+    backend, engine = task_backend(cfg, spans)
+    engine_name, shards = layout(cfg)
 
     def set_task(row: np.ndarray) -> None:
+        # a task is padded to a multiple of the engine's start-batch
+        # granularity (the mesh size for dist), known once prepared
+        row = np.pad(row, (0, -row.shape[0] % backend.granularity),
+                     constant_values=-1)
         valid = row >= 0
         backend.task = (np.where(valid, row, n).astype(np.int32), valid)
 
     t = time.perf_counter()
     backend.prepare(plan, graph, config)
-    jax.block_until_ready((backend.dg.rows, backend.dg.lane_rows))
+    jax.block_until_ready(engine.arrays(backend))
     log["upload_s"] = time.perf_counter() - t
     t = time.perf_counter()
     for row in tasks[-1 - mix["warm_tasks"]:-1]:   # full tasks off the end
@@ -92,13 +155,15 @@ def run_cell(cfg: Dict, mix: Dict, seed: int, seconds: float,
         drive(backend, plan, graph, config)
     log["warm_s"] = time.perf_counter() - t
     log.update(n=n, m=int(indices.shape[0] // 2),
-               max_degree=int(np.diff(indptr).max()), width=backend.dg.d,
+               max_degree=int(np.diff(indptr).max()),
+               engine=engine_name, shards=shards,
+               width=engine.width(backend),
                pattern=mix["pattern"], task_size=B, caps=mix["caps"],
                instrs=len(plan.instrs))
 
     counts, ran, failed = [], [], 0
     chunks = splits = retries = 0
-    lv0 = backend._level_acc.copy()
+    rows0 = enu_rows(backend)
     with capture():
         window_start = time.perf_counter()
         with spans.span("window"):
@@ -126,7 +191,8 @@ def run_cell(cfg: Dict, mix: Dict, seed: int, seconds: float,
     matches = int(got[got >= 0].sum())
     run_tasks = tasks[ran]
     accepted = chunks - splits - retries
-    level_rows = backend._level_acc - lv0
+    # the capacity the engine applied: its caps, per shard on a mesh
+    capacity = accepted * shards * sum(backend.initial_caps(config))
     log.update(tasks=len(ran), window_s=window_s, matches=matches,
                chunks_run=chunks, chunks_split=splits,
                chunks_retried=retries)
@@ -134,8 +200,8 @@ def run_cell(cfg: Dict, mix: Dict, seed: int, seconds: float,
         attempted=len(ran), failed=failed,
         e2e={"census_matches_per_s": matches / window_s},
         layer={"spans": spans, "window_s": window_s,
-               "enu_rows": int(level_rows.sum()),
-               "enu_capacity_rows": int(accepted * sum(mix["caps"])),
+               "enu_rows": enu_rows(backend) - rows0,
+               "enu_capacity_rows": int(capacity),
                "least_int_bytes": (workmodel.triangle_int_bytes(
                    indptr, indices, run_tasks)
                    if mix["pattern"] == "triangle" else None)},

@@ -4,7 +4,11 @@ A configuration is ``BENCHMARK.json``'s ``file``; a traffic mix is
 ``traffic/<traffic>.json``; a per-layer metric is read by
 ``metrics/<name>.py``, whose ``read(ctx)`` returns the number or None.
 Adding a cell, a configuration, a mix or a metric adds files and entries;
-nothing here changes.
+nothing here changes. A census configuration also names its engine and
+the chips that share its rows (``engine``, ``shards``; census.py), so a
+four-chip census cell is a configuration file with ``"engine": "dist",
+"shards": 4`` and a cell with ``"chips": 4``, named in the metrics it
+reports: files and entries only.
 """
 
 from __future__ import annotations

@@ -1,5 +1,6 @@
-"""Device time of ENU compaction as a share of the census window (%):
-device ops under the program's ``enu`` scope (engine_jax._expand), from
+"""Device time of ENU compaction as a share of the census window (%), per
+chip: device ops under the program's ``enu`` scope (engine_jax._expand),
+summed over the chips that ran anything and over that many chips, from
 the profiler trace (progtrace.py)."""
 
 import progtrace

@@ -2,7 +2,9 @@
 triangle plan's intersections read for the tasks run (workmodel.py, from
 real adjacency lengths) over HBM bandwidth times the kernels' device time.
 Bytes bound it: a merge makes about one compare per entry, far under the
-chip's integer peak."""
+chip's integer peak. The kernels' seconds are summed over the chips, not
+taken per chip: on a mesh the least bytes are spread over all of them,
+so the roofline is the HBM bandwidth times the aggregate chip-seconds."""
 
 from tracing import kernel_seconds
 

@@ -3,9 +3,9 @@
 Two sources, both written by the program (``src/repro``), never by the
 benchmark's files:
 
-* the profiler's ``.xplane.pb`` of the run (the newest under
-  ``.bench_cache/trace/``): every device op with the layer scope it ran
-  under (``enu``, ``int``, ``dbq``, ``derive``: the program's
+* the profiler's ``.xplane.pb`` of the run (the newest under the run's
+  ``.bench_cache/trace/<cell>/``): every device op with the layer scope
+  it ran under (``enu``, ``int``, ``dbq``, ``derive``: the program's
   ``jax.named_scope``s, carried in the op's metadata), or failing that
   the jitted program it belongs to (``derive`` is a program of its own);
   and the host spans ``repro.*`` of ``repro.obs``, with the benchmark's
@@ -29,7 +29,6 @@ import numpy as np
 
 from tracing import _union
 
-TRACE_DIR = Path(__file__).resolve().parent.parent / ".bench_cache" / "trace"
 SCOPES = ("enu", "int", "dbq", "derive")
 HOST_PREFIX = "repro."
 WINDOW = "bench.window"
@@ -151,13 +150,12 @@ def scope_of(stats: Dict[str, str], module: str = "") -> Optional[str]:
     return hit[-1] if hit else None
 
 
-def load(trace_dir: Optional[Path] = None) -> Optional[Dict]:
+def load(trace_dir: Path) -> Optional[Dict]:
     """The newest trace under ``trace_dir`` as a plain record (read once):
 
     ``{"device": {plane: [[op, start_ns, dur_ns, scope], ...]},
     "host": [[span, start_ns, dur_ns], ...]}``, with the host spans
     ``repro.*`` and ``bench.*`` only. None without a trace."""
-    trace_dir = TRACE_DIR if trace_dir is None else trace_dir
     files = sorted(Path(trace_dir).rglob("*.xplane.pb"),
                    key=lambda p: p.stat().st_mtime)
     if not files:
@@ -225,6 +223,17 @@ def scope_seconds(rec: Dict) -> Dict[Optional[str], float]:
     return out
 
 
+def scope_seconds_per_chip(rec: Dict) -> Dict[Optional[str], float]:
+    """:func:`scope_seconds` per chip: summed over the device planes, over
+    the planes that ran anything in the window (one plane: the same
+    numbers). A share of the window taken from it cannot pass 100%."""
+    lo, hi = window(rec)
+    chips = sum(any(min(s + d, hi) > max(s, lo) for _, s, d, _ in evs)
+                for evs in rec["device"].values())
+    return {k: v / chips for k, v in scope_seconds(rec).items()} \
+        if chips else {}
+
+
 def host_spans(rec: Dict, name: str) -> List[Tuple[int, int]]:
     """``[start, end)`` ns of host span ``repro.<name>`` that start in the
     window."""
@@ -253,32 +262,34 @@ def covered(union: np.ndarray, a: int, b: int) -> int:
 
 
 def trace_record(ctx: Dict) -> Optional[Dict]:
-    """This run's trace record, or None (an untraced run, no window)."""
-    if not ctx.get("trace"):
+    """This run's trace record (the newest under ``ctx["trace_dir"]``,
+    where the run put it), or None (an untraced run, no window)."""
+    if not ctx.get("trace") or not ctx.get("trace_dir"):
         return None
-    rec = load()
+    rec = load(ctx["trace_dir"])
     if rec is None or window(rec) is None:
         return None
     return rec
 
 
 def scope_share(ctx: Dict, scope: str) -> Optional[float]:
-    """Device time under ``scope`` over the window (%)."""
+    """Device time per chip under ``scope`` over the window (%)."""
     rec = trace_record(ctx)
     if rec is None:
         return None
-    sec = scope_seconds(rec).get(scope)
+    sec = scope_seconds_per_chip(rec).get(scope)
     lo, hi = window(rec)
     return 100.0 * sec / ((hi - lo) * 1e-9) if sec else None
 
 
 def scope_ms_per_step(ctx: Dict, scope: str) -> Optional[float]:
-    """Device milliseconds under ``scope`` per traced time step."""
+    """Device milliseconds per chip under ``scope`` per traced time
+    step."""
     rec = trace_record(ctx)
     if rec is None:
         return None
     steps = host_spans(rec, "timestep")
-    sec = scope_seconds(rec).get(scope)
+    sec = scope_seconds_per_chip(rec).get(scope)
     return 1e3 * sec / len(steps) if steps and sec else None
 
 

@@ -38,7 +38,8 @@ import devicekit  # noqa: E402
 import manifest  # noqa: E402
 import tracing  # noqa: E402
 
-TRACE_DIR = HERE.parent / ".bench_cache" / "trace"
+#: traces go under the manifest's root, one directory per cell
+TRACE_DIR = Path(".bench_cache") / "trace"
 
 
 class Window:
@@ -100,7 +101,7 @@ def run(args, require_chip: bool = True,
     devices = jax.devices()[:cell["chips"]]
     logdir = None
     if args.trace:
-        logdir = TRACE_DIR / args.workload
+        logdir = bench.root / TRACE_DIR / args.workload
         shutil.rmtree(logdir, ignore_errors=True)
     window = Window(counter, logdir)
     spans = tracing.Spans(annotate=bool(args.trace))
@@ -140,7 +141,7 @@ def run(args, require_chip: bool = True,
         result["breakdown"] = {"device_ops": summary["device_ops"],
                                "idle_gaps": summary["idle_gaps"]}
         peaks = devicekit.peaks(dev["kind"]) if require_chip else {}
-        ctx = {"trace": summary, "peaks": peaks,
+        ctx = {"trace": summary, "trace_dir": logdir, "peaks": peaks,
                "window": (window.start, window.end), **out.layer}
         metrics = {}
         for m in bench.per_layer(args.workload):

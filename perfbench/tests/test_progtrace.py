@@ -125,7 +125,8 @@ def _excerpt(cell):
 
 def _with_trace(monkeypatch, rec):
     monkeypatch.setattr(progtrace, "load", lambda *a, **k: rec)
-    return {"trace": {"window_s": 1.0}, "window": (0.0, 1e12)}
+    return {"trace": {"window_s": 1.0}, "trace_dir": Path("unread"),
+            "window": (0.0, 1e12)}
 
 
 def test_synthetic_trace_reads():
@@ -143,6 +144,26 @@ def test_synthetic_trace_reads():
     assert progtrace.covered(u, 50, 350) == 130
 
 
+def test_per_chip_scope_reads_on_a_four_chip_trace(monkeypatch):
+    """Four device planes with 100, 200, 300 and 400 ns under ``enu`` in a
+    1,000-ns window with two steps, and a fifth whose only op lies outside
+    the window: the per-chip readings are the mean over the four."""
+    rec = {"device": {f"/device:TPU:{i}": [["a", 0, 100 * (i + 1), "enu"],
+                                           ["b", 500, 40, "dbq"]]
+                      for i in range(4)},
+           "host": [["bench.window", 0, 1000], ["repro.timestep", 0, 400],
+                    ["repro.timestep", 400, 400]]}
+    rec["device"]["/device:TPU:4"] = [["a", 2000, 100, "enu"]]
+    summed = progtrace.scope_seconds(rec)
+    assert summed["enu"] == pytest.approx(1000e-9)
+    per = progtrace.scope_seconds_per_chip(rec)
+    assert per == {"enu": pytest.approx(250e-9), "dbq": pytest.approx(40e-9)}
+    ctx = _with_trace(monkeypatch, rec)
+    ctx["trace"]["window_s"] = 1000e-9
+    assert reader("enu_share.census")(ctx) == pytest.approx(25.0)
+    assert reader("dbq_device_ms.stream")(ctx) == pytest.approx(20e-6)
+
+
 def test_step_idle_share_on_synthetic_trace(monkeypatch):
     rec = {"device": {"/device:TPU:0": [["a", 100, 100, "enu"]]},
            "host": [["bench.window", 0, 1000], ["repro.timestep", 0, 400]]}
@@ -158,6 +179,8 @@ def test_trace_readers_on_recorded_chip_excerpt(monkeypatch, cell):
     rec = _excerpt(cell)
     ctx = _with_trace(monkeypatch, rec)
     scopes = progtrace.scope_seconds(rec)
+    # one device plane: the per-chip readings are the summed ones, exactly
+    assert progtrace.scope_seconds_per_chip(rec) == scopes
     busy = sum(scopes.values())
     scoped = sum(v for k, v in scopes.items() if k is not None)
     assert scoped >= 0.9 * busy
@@ -204,8 +227,8 @@ def test_readers_return_none_without_input(monkeypatch, tmp_path, name):
     obs.reset()
     read = reader(name)
     assert read({}) is None                                 # untraced
-    monkeypatch.setattr(progtrace, "TRACE_DIR", tmp_path)
-    assert read({"trace": {"window_s": 1.0}, "window": (0.0, 1.0)}) is None
+    assert read({"trace": {"window_s": 1.0}, "trace_dir": tmp_path,
+                 "window": (0.0, 1.0)}) is None              # no trace
     # a program without repro.obs, or without scopes in its trace
     monkeypatch.setattr(progtrace, "_obs", lambda: None)
     rec = {"device": {"/device:TPU:0": [["x", 10, 5, None]]},

@@ -137,12 +137,14 @@ def reduce_trace(trace: Dict[str, object], lo: int, hi: int,
     ``busy_s``: the union of op intervals on each device, averaged over
     the devices that ran anything; ``window_s``: ``hi - lo``;
     ``op_s``: device seconds per op name (summed over devices, clipped to
-    the window); ``device_ops``: the ``top`` names by time; ``idle_gaps``:
+    the window); ``chips``: the devices that ran an op inside the window;
+    ``device_ops``: the ``top`` names by time; ``idle_gaps``:
     the ``top`` longest gaps of device 0, each named by the innermost
     benchmark span that covers its midpoint (``"none"`` where no span
     does).
     """
     busy = []
+    chips = 0
     op_s: Dict[str, float] = {}
     gaps: List[Tuple[str, float]] = []
     for k, plane in enumerate(sorted(trace["device"])):
@@ -155,6 +157,7 @@ def reduce_trace(trace: Dict[str, object], lo: int, hi: int,
                 op_s[name] = op_s.get(name, 0.0) + (b - a) * 1e-9
         u = _clip(_union(iv), lo, hi)
         busy.append(float((u[:, 1] - u[:, 0]).sum()) * 1e-9)
+        chips += u.shape[0] > 0
         if k == 0:
             edges = np.r_[lo, u.ravel(), hi].reshape(-1, 2)
             width = edges[:, 1] - edges[:, 0]
@@ -165,7 +168,7 @@ def reduce_trace(trace: Dict[str, object], lo: int, hi: int,
                                  float(width[i]) * 1e-9))
     ops = sorted(op_s.items(), key=lambda kv: -kv[1])
     return {"busy_s": float(np.mean(busy)) if busy else 0.0,
-            "window_s": (hi - lo) * 1e-9, "op_s": op_s,
+            "window_s": (hi - lo) * 1e-9, "op_s": op_s, "chips": chips,
             "device_ops": [[n.split("(", 1)[0], s] for n, s in ops[:top]],
             "idle_gaps": [[n, s] for n, s in gaps[:top]]}
 
@@ -192,3 +195,12 @@ def kernel_seconds(summary: Dict[str, object], patterns: Sequence[str]
     ``patterns`` (an op's operands are named in its text too)."""
     return sum(s for n, s in summary["op_s"].items()
                if any(p in op_name(n) for p in patterns))
+
+
+def kernel_seconds_per_chip(summary: Dict[str, object],
+                            patterns: Sequence[str]) -> float:
+    """:func:`kernel_seconds` per chip: summed over the device planes, over
+    the planes that ran anything in the window (one plane: the same
+    number). A share of the window taken from it cannot pass 100%."""
+    chips = summary["chips"]
+    return kernel_seconds(summary, patterns) / chips if chips else 0.0

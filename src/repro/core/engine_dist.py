@@ -99,6 +99,10 @@ def build_distributed_step(plan: Plan,
     ``shards``: int32[S, rps, D] sharded over ``axis``; ``hot_rows``
     replicated; ``starts``/``starts_valid``: [S*B] sharded. This function is
     what the multi-pod dry-run lowers for the paper's own technique.
+
+    The step's ``fetches`` attribute is the number of distributed DBQ
+    fetches a shard makes per call, each exchanging a ``[S, req_cap]``
+    request/response buffer: None until the first call traces it.
     """
     has_universe = check_jit_supported(plan)
     S = spec.n_shards
@@ -122,6 +126,7 @@ def build_distributed_step(plan: Plan,
             res = run(starts, starts_valid, uni)
         else:
             res = run(starts, starts_valid)
+        step.fetches = len(fetch_stats)
         cold = sum((c for c, _ in fetch_stats), jnp.zeros((), jnp.int32))
         drops = sum((d for _, d in fetch_stats), jnp.zeros((), jnp.int32))
         levels = (jnp.stack(res.level_sizes)[:, None]
@@ -135,7 +140,9 @@ def build_distributed_step(plan: Plan,
         in_specs.append(P(None))
     fn = shard_map(local_fn, mesh=mesh, in_specs=tuple(in_specs),
                    out_specs=out_specs, check_vma=False)
-    return jax.jit(fn)
+    step = jax.jit(fn)
+    step.fetches = None
+    return step
 
 
 def enumerate_distributed(plan: Plan, graph: Graph,

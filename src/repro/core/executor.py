@@ -578,7 +578,13 @@ class JaxGpuBackend(JaxBackend):
 
 
 class DistBackend(ExecutorBackend):
-    """Mesh-wide SPMD frontier engine with the distributed row store."""
+    """Mesh-wide SPMD frontier engine with the distributed row store.
+
+    The rows are placed shard by shard (``place_row_shards``), and each
+    chunk's starts are dealt round-robin over the shards (start ``i`` to
+    shard ``i mod S``): a census task lists its starts by degree band, so
+    contiguous blocks would hand the last shard all the heavy ones.
+    """
 
     name = "dist"
 
@@ -592,10 +598,15 @@ class DistBackend(ExecutorBackend):
 
     def prepare(self, plan: Plan, source: Graph,
                 config: ExecutorConfig) -> None:
+        with obs.span("prepare"):
+            self._prepare(plan, source, config)
+
+    def _prepare(self, plan: Plan, source: Graph,
+                 config: ExecutorConfig) -> None:
         import jax
         import jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
-        from ..distributed.rowstore import build_row_shards
+        from ..distributed.rowstore import place_row_shards
         from .engine_jax import check_jit_supported, default_caps
         from .engine_dist import enumeration_mesh
         self.plan, self.graph = plan, source
@@ -605,8 +616,8 @@ class DistBackend(ExecutorBackend):
         self.S = mesh.devices.size
         self.granularity = self.S
         self.cap_multiple = self.S       # rebalancer stripes (driver rounds)
-        shards_np, hot_np, spec = build_row_shards(source, self.S,
-                                                   hot=self._hot)
+        self.shards, self.hot_rows, spec = place_row_shards(
+            source, mesh, self._axis, hot=self._hot)
         self.spec = spec
         self.sentinel = spec.n
         self.has_universe = check_jit_supported(plan)
@@ -618,11 +629,6 @@ class DistBackend(ExecutorBackend):
         self.req_cap = self._req_cap0 if self._req_cap0 is not None else \
             max(64, 2 * batch_per_shard // self.S)
         self._intersect = config.intersect_impl
-        with jax.default_device(jax.devices()[0]):
-            self.shards = jax.device_put(
-                shards_np, NamedSharding(mesh, P(self._axis, None, None)))
-            self.hot_rows = jax.device_put(
-                hot_np, NamedSharding(mesh, P(None, None)))
         self._uni = [
             jax.device_put(jnp.asarray(c), NamedSharding(mesh, P(None)))
             for c in build_universe_chunks(source.n, config.universe_chunk)
@@ -648,37 +654,56 @@ class DistBackend(ExecutorBackend):
 
     def escalate_requests(self) -> None:
         self.req_cap *= 2
+        obs.count("chunks.req_escalated")
 
     def _step(self, caps: Tuple[int, ...], req_cap: int) -> Callable:
         key = (caps, req_cap)
         if key not in self._steps:
             from .engine_dist import build_distributed_step
-            self._steps[key] = build_distributed_step(
+            self._steps[key] = obs.build_on_first_call(build_distributed_step(
                 self.plan, self.spec, self.mesh, self._axis, caps, req_cap,
-                rebalance=self._rebalance, intersect_impl=self._intersect)
+                rebalance=self._rebalance, intersect_impl=self._intersect))
         return self._steps[key]
 
+    def deal(self, x: np.ndarray) -> np.ndarray:
+        """``x`` (a chunk's starts) reordered so that shard ``s``'s block
+        holds elements ``s, s + S, s + 2S, ...``."""
+        return np.ascontiguousarray(x.reshape(-1, self.S).T).reshape(-1)
+
     def run_chunk(self, ids, valid, universe_chunk, caps) -> ChunkResult:
+        with obs.span("chunk"):
+            return self._run_chunk(ids, valid, universe_chunk, caps)
+
+    def _run_chunk(self, ids, valid, universe_chunk, caps) -> ChunkResult:
         import jax
-        import jax.numpy as jnp
-        args = [self.shards, self.hot_rows,
-                jax.device_put(jnp.asarray(ids), self._id_sharding),
-                jax.device_put(jnp.asarray(valid), self._id_sharding)]
-        if universe_chunk is not None:
-            args.append(universe_chunk)
-        counts, overflow, cold, drops, levels = self._step(
-            caps, self.req_cap)(*args)
-        ov = int(np.sum(np.asarray(overflow)))
-        dr = int(np.sum(np.asarray(drops)))
-        if ov == 0 and dr == 0:
+        with obs.span("chunk.dispatch"):
+            args = [self.shards, self.hot_rows,
+                    jax.device_put(self.deal(ids), self._id_sharding),
+                    jax.device_put(self.deal(valid), self._id_sharding)]
+            if universe_chunk is not None:
+                args.append(universe_chunk)
+            step = self._step(caps, self.req_cap)
+            counts, overflow, cold, drops, levels = step(*args)
+        with obs.span("chunk.wait"):
+            ov = int(np.sum(np.asarray(overflow)))
+            dr = int(np.sum(np.asarray(drops)))
+        with obs.span("chunk.decode"):
+            if dr:
+                obs.count("dbq.req_drops", dr)
+            if ov or dr:
+                return ChunkResult(count=0, overflow=ov, drops=dr)
             counts64 = np.asarray(counts, dtype=np.int64)
             self._per_shard += counts64
-            self._cold += int(np.sum(np.asarray(cold)))
+            n_cold = int(np.sum(np.asarray(cold)))
+            self._cold += n_cold
+            obs.count("dbq.cold_rows", n_cold)
+            # every shard's response buffers: [S, R] rows a fetch
+            obs.count("dbq.req_slots",
+                      self.S * self.S * self.req_cap * step.fetches)
             lv = np.asarray(levels)
             self._level_acc = (lv if self._level_acc is None
                                else self._level_acc + lv)
             return ChunkResult(count=int(counts64.sum()))
-        return ChunkResult(count=0, overflow=ov, drops=dr)
 
     def finalize(self, stats: ExecStats) -> None:
         stats.extras.update(

@@ -26,7 +26,6 @@ distributed-DB hotspot the paper's cache also exists to absorb).
 
 from __future__ import annotations
 
-import functools
 from dataclasses import dataclass
 from typing import Optional, Tuple
 
@@ -34,7 +33,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..graph.storage import Graph, pad_rows
+from .. import obs
+from ..graph.storage import Graph, pad_rows, padded_width
 
 
 @dataclass
@@ -53,28 +53,78 @@ class RowStoreSpec:
         return self.n_shards * self.rows_per_shard
 
 
+def row_store_spec(graph: Graph, n_shards: int, hot: int = 0,
+                   lane: int = 128, d_max: Optional[int] = None
+                   ) -> RowStoreSpec:
+    """The block-partitioned layout of ``graph``'s padded rows over
+    ``n_shards``: rows 0..n-1, then the sentinel row ``n`` and the shard
+    padding (empty adjacencies), ``rows_per_shard`` to a shard."""
+    n = graph.n
+    d = padded_width(int(np.max(graph.deg, initial=0)), d_max=d_max,
+                     lane=lane)
+    return RowStoreSpec(n=n, d=d, n_shards=n_shards,
+                        rows_per_shard=-(-(n + 1) // n_shards),
+                        hot=min(hot, n))
+
+
+def _pack(graph: Graph, spec: RowStoreSpec, lo: int, hi: int) -> np.ndarray:
+    """Rows ``[lo, hi)`` of the padded table, ``int32[hi - lo, D]``."""
+    adj = list(graph.adj[lo:min(hi, spec.n)])
+    empty = [np.zeros(0, np.int64)] * (hi - lo - len(adj))
+    return pad_rows(adj + empty, spec.n, d_max=spec.d, lane=1)
+
+
+def pack_shard(graph: Graph, spec: RowStoreSpec, s: int) -> np.ndarray:
+    """Shard ``s``'s block of the padded table, ``int32[rps, D]``."""
+    rps = spec.rows_per_shard
+    return _pack(graph, spec, s * rps, (s + 1) * rps)
+
+
+def pack_hot_rows(graph: Graph, spec: RowStoreSpec) -> np.ndarray:
+    """The replicated rows, ``int32[hot + 1, D]``: ids ``[n - hot, n]``,
+    the sentinel row last (relabeling is ascending-degree, so these are
+    the ``hot`` highest-degree vertices)."""
+    return _pack(graph, spec, spec.n - spec.hot, spec.n + 1)
+
+
 def build_row_shards(graph: Graph, n_shards: int, hot: int = 0,
                      lane: int = 128, d_max: Optional[int] = None
                      ) -> Tuple[np.ndarray, np.ndarray, RowStoreSpec]:
-    """Materialize ``(shards [S, rps, D], hot_rows [hot, D], spec)``.
+    """Materialize ``(shards [S, rps, D], hot_rows [hot + 1, D], spec)``
+    on the host, the whole table at once.
 
     Row ``n`` (the sentinel row, all holes) is stored like any other row, so
-    gathers with invalid ids round-trip safely.
+    gathers with invalid ids round-trip safely. :func:`place_row_shards`
+    puts the same layout on a mesh one shard at a time.
     """
-    n = graph.n
-    rps = -(-(n + 1) // n_shards)
-    # the sentinel row and the shard padding are empty adjacencies, packed
-    # in the same pass (one host copy of the table)
-    empty = np.zeros(0, np.int64)
-    rows = pad_rows(list(graph.adj) + [empty] * (n_shards * rps - n), n,
-                    d_max=d_max, lane=lane)
-    d = rows.shape[1]
-    shards = rows.reshape(n_shards, rps, d)
-    hot = min(hot, n)
-    # relabeling is ascending-degree, so the hot set is ids [n-hot, n]
-    hot_rows = rows[n - hot:n + 1] if hot > 0 else rows[n:n + 1]
-    spec = RowStoreSpec(n=n, d=d, n_shards=n_shards, rows_per_shard=rps,
-                        hot=hot)
+    spec = row_store_spec(graph, n_shards, hot=hot, lane=lane, d_max=d_max)
+    shards = np.stack([pack_shard(graph, spec, s) for s in range(n_shards)])
+    return shards, pack_hot_rows(graph, spec), spec
+
+
+def place_row_shards(graph: Graph, mesh, axis: str, hot: int = 0,
+                     lane: int = 128
+                     ) -> Tuple[jax.Array, jax.Array, RowStoreSpec]:
+    """:func:`build_row_shards`'s layout on ``mesh``: the rows sharded
+    over ``axis``, the hot rows on every device. Each shard is packed on
+    the host and put on its own device before the next is packed, so the
+    host never holds more than one shard of the table (B-BENU's workers
+    each load their own region)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    spec = row_store_spec(graph, mesh.shape[axis], hot=hot, lane=lane)
+    shape = (spec.n_shards, spec.rows_per_shard, spec.d)
+    sharding = NamedSharding(mesh, P(axis, None, None))
+    parts = []
+    for dev, idx in sharding.addressable_devices_indices_map(shape).items():
+        with obs.span("rowstore.pack"):
+            host = pack_shard(graph, spec, idx[0].start or 0)
+        with obs.span("rowstore.place"):
+            parts.append(jax.block_until_ready(
+                jax.device_put(host[None], dev)))
+        del host
+    shards = jax.make_array_from_single_device_arrays(shape, sharding, parts)
+    hot_rows = jax.device_put(pack_hot_rows(graph, spec),
+                              NamedSharding(mesh, P(None, None)))
     return shards, hot_rows, spec
 
 

@@ -200,24 +200,36 @@ def _apply_filters(sets: jax.Array, filters, env: Dict[Var, jax.Array],
     return out
 
 
-def _flat_cumsum(x: jax.Array) -> jax.Array:
-    """Inclusive prefix sum of a ``[R, C]`` array in flat row-major order.
+def _scan(x: jax.Array) -> jax.Array:
+    """Inclusive prefix sum of a 1-D array, blocked in 128-lane rows.
 
-    Row-wise cumsums plus an exclusive scan of the row totals (recursing
-    in 128-lane rows). Same integers as ``jnp.cumsum(x.reshape(-1))``,
-    but the TPU compiler takes seconds to minutes on one long 1-D cumsum
-    (32 s at 2**20 elements on a v5e target) and under a second on this
-    blocked form.
+    Same integers as ``jnp.cumsum(x)``, but the TPU compiler takes seconds
+    to minutes on one long 1-D cumsum (32 s at 2**20 elements on a v5e
+    target) and under a second on this blocked form.
     """
-    inner = jnp.cumsum(x, axis=1)
+    r = x.shape[0]
+    if r <= 128:
+        return jnp.cumsum(x)
+    inner = jnp.cumsum(jnp.pad(x, (0, -r % 128)).reshape(-1, 128), axis=1)
     tot = inner[:, -1]
-    r = tot.shape[0]
-    if r > 128:
-        tot_p = jnp.pad(tot, (0, -r % 128)).reshape(-1, 128)
-        csum = _flat_cumsum(tot_p)[:r]
-    else:
-        csum = jnp.cumsum(tot)
-    return (inner + (csum - tot)[:, None]).reshape(-1)
+    return (inner + (_scan(tot) - tot)[:, None]).reshape(-1)[:r]
+
+
+def _count_le(read: Callable[[jax.Array], jax.Array], x: jax.Array,
+              n: int) -> jax.Array:
+    """Per query ``x``, how many of the first ``n`` values of a
+    non-decreasing sequence are ``<= x`` (the index of the first value
+    above ``x``), capped at ``n - 1``.
+
+    A fixed-trip binary search: ``(n - 1).bit_length()`` rounds, each one
+    ``read(idx)`` of an index per query (``0 <= idx < n``).
+    """
+    pos = jnp.zeros_like(x)
+    for k in reversed(range((n - 1).bit_length())):
+        nxt = pos + (1 << k)
+        ok = (nxt < n) & (read(jnp.minimum(nxt, n - 1) - 1) <= x)
+        pos = jnp.where(ok, nxt, pos)
+    return pos
 
 
 def _expand(env: Dict[Var, jax.Array], valid: jax.Array,
@@ -232,37 +244,43 @@ def _expand(env: Dict[Var, jax.Array], valid: jax.Array,
     ``cand``) to env vars of the child frontier — the S-BENU Delta-ENU uses
     this to carry each candidate's ± snapshot selector alongside its vertex.
 
-    Compaction of the valid children to the front:
-      * "cumsum": positions by prefix-sum + one scatter — O(n) HBM traffic.
+    Compaction of the valid children to the front, in flat row-major order:
+      * "cumsum": one streaming pass takes the row-wise prefix sums of the
+        valid mask and the row offsets; then each child slot ``j < cap``
+        finds its candidate by two binary searches, for its parent row
+        (the first whose inclusive offset exceeds ``j``) and for its column
+        in that row (the first whose row prefix sum exceeds its rank).
+        Cost: one pass over the B x D candidates plus cap x log2(B x D)
+        reads; no candidate is scattered.
       * "sort":   stable argsort on the invalid mask — XLA lowers to a
-        bitonic network, O(n log^2 n) passes over the buffer. Kept as the
-        §Perf baseline; the cumsum path cut the BENU cell's memory term
-        ~2.8x (EXPERIMENTS.md).
-    Both orders are identical (prefix-sum preserves flat order; the argsort
-    was stable), so results are bit-equal.
+        bitonic network, O(n log^2 n) passes over the buffer.
+    Both give the same order, so results are bit-equal.
     """
     with jax.named_scope("enu"):
         B, D = cand.shape
         n = B * D
         flat = cand.reshape(n)
         valid2 = (cand != sentinel) & valid[:, None]
-        fvalid = valid2.reshape(n)
-        parent = jnp.repeat(jnp.arange(B, dtype=jnp.int32), D)
         if compaction == "sort":
+            fvalid = valid2.reshape(n)
             order = jnp.argsort(~fvalid, stable=True)    # valid rows first
             take = order[:cap]
             new_valid = fvalid[take]
-            parents = parent[take]
+            parents = jnp.repeat(jnp.arange(B, dtype=jnp.int32), D)[take]
+            total = jnp.sum(fvalid)
         else:
-            pos = _flat_cumsum(valid2.astype(jnp.int32)) - 1
-            slot = jnp.where(fvalid & (pos < cap), pos, cap)
-            take = jnp.full((cap + 1,), n, jnp.int32)
-            take = take.at[slot].set(jnp.arange(n, dtype=jnp.int32),
-                                     mode="drop")[:cap]
-            new_valid = take < n
-            take = jnp.where(new_valid, take, 0)
-            parents = parent[take]
-        total = jnp.sum(fvalid)
+            inner = jnp.cumsum(valid2.astype(jnp.int32), axis=1)
+            tot = inner[:, -1]
+            rincl = _scan(tot)
+            total = rincl[-1]
+            j = jnp.arange(cap, dtype=jnp.int32)
+            p = _count_le(lambda i: rincl[i], j, B)
+            rank = j - (rincl[p] - tot[p])
+            row = inner.reshape(n)
+            c = _count_le(lambda i: row[p * D + i], rank, D)
+            new_valid = j < total
+            parents = jnp.where(new_valid, p, 0)
+            take = jnp.where(new_valid, p * D + c, 0)
         overflow = jnp.maximum(total - jnp.sum(new_valid), 0)
         new_env: Dict[Var, jax.Array] = {}
         for v, arr in env.items():

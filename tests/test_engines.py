@@ -130,3 +130,65 @@ def test_task_splitting_bounds_work():
         max(eng_a.counters.per_task_work)
     assert len(eng_b.counters.per_task_work) > \
         len(eng_a.counters.per_task_work)
+
+
+# (B, D, hole share, invalid-parent share, cap from (total, B * D))
+EXPAND_CASES = {
+    "cap-below": (16, 8, 0.5, 0.0, lambda t, n: t // 2),
+    "cap-at": (16, 8, 0.5, 0.0, lambda t, n: t),
+    "cap-above": (16, 8, 0.5, 0.0, lambda t, n: t + 5),
+    "cap-above-BxD": (16, 8, 0.5, 0.0, lambda t, n: n + 7),
+    "all-holes": (16, 8, 1.0, 0.0, lambda t, n: 10),
+    "no-holes": (16, 8, 0.0, 0.0, lambda t, n: t - 3),
+    "invalid-parents": (32, 16, 0.05, 0.3, lambda t, n: t - 1),
+    "one-row": (1, 16, 0.5, 0.0, lambda t, n: t),
+    "one-column": (40, 1, 0.5, 0.0, lambda t, n: t // 2),
+    "rows-over-128": (300, 5, 0.8, 0.1, lambda t, n: t),
+    "rows-over-128x128": (16500, 2, 0.9, 0.0, lambda t, n: t - 10),
+}
+
+
+@pytest.mark.parametrize("case", sorted(EXPAND_CASES))
+def test_expand_matches_flat_order_compaction(case):
+    """ENU compaction keeps the valid candidates in flat row-major order,
+    carries the live columns and extra columns of each child's parent
+    and candidate, and counts what does not fit as overflow: the same
+    arrays as a plain numpy compaction, and as ``compaction="sort"``."""
+    from repro.core.engine_jax import _expand
+    B, D, holes, invalid, cap_of = EXPAND_CASES[case]
+    S = 1000
+    rng = np.random.default_rng(B * 1000 + D)
+    cand = np.where(rng.random((B, D)) < holes, S,
+                    rng.integers(0, S, (B, D))).astype(np.int32)
+    valid = rng.random(B) >= invalid
+    col = rng.integers(0, S, B).astype(np.int32)
+    sel = rng.integers(0, 2, (B, D)).astype(np.int32)
+    fvalid = ((cand != S) & valid[:, None]).reshape(-1)
+    idx = np.nonzero(fvalid)[0]
+    cap = cap_of(len(idx), B * D)
+    kept = idx[:cap]
+    k = len(kept)
+
+    def pad(x, fill):
+        return np.concatenate([x, np.full(cap - k, fill, x.dtype)])
+
+    want = {("A", 0): pad(col[kept // D], col[0]),
+            ("A", 1): pad(cand.reshape(-1)[kept], S),
+            ("s", 1): pad(sel.reshape(-1)[kept], 0)}
+    want_valid = np.arange(cap) < k
+    args = ({("A", 0): col, ("f", 0): col}, valid, cand, ("A", 1), cap,
+            frozenset({("A", 0)}), S)
+    env, new_valid, overflow = _expand(*args, extra_cols={("s", 1): sel})
+    assert set(env) == set(want)
+    for v, arr in want.items():
+        np.testing.assert_array_equal(np.asarray(env[v]), arr, err_msg=v)
+    np.testing.assert_array_equal(np.asarray(new_valid), want_valid)
+    assert int(overflow) == max(len(idx) - cap, 0)
+    if cap <= B * D:         # the sort path takes at most B * D children
+        env_s, valid_s, overflow_s = _expand(
+            *args, compaction="sort", extra_cols={("s", 1): sel})
+        np.testing.assert_array_equal(np.asarray(valid_s), want_valid)
+        for v, arr in env.items():
+            np.testing.assert_array_equal(np.asarray(env_s[v])[:k],
+                                          np.asarray(arr)[:k], err_msg=v)
+        assert int(overflow_s) == int(overflow)

@@ -93,3 +93,22 @@ def test_triangle_chunk_program_compiles(one_chip, fused):
         _spec(one_chip, ((N + 1) * width // 128, 128)),
         _spec(one_chip, (64,)), _spec(one_chip, (64,), jnp.bool_)).compile()
     _assert_kernel(compiled)
+
+
+def test_enu_compaction_has_no_scatter(one_chip):
+    """ENU compaction at the census's level-2 shape (16,384 rows of 4,096
+    candidates into 24,576 child slots) searches the prefix sums: no
+    scatter of the B x D candidates, which the TPU serializes."""
+    from repro.core.engine_jax import _expand
+    B, D, cap = 16384, 4096, 24576
+    u, c = ("A", 0), ("A", 1)
+
+    def compact(col, valid, cand):
+        return _expand({u: col}, valid, cand, c, cap, frozenset({u}), N)
+
+    compiled = jax.jit(compact).lower(
+        _spec(one_chip, (B,)), _spec(one_chip, (B,), jnp.bool_),
+        _spec(one_chip, (B, D))).compile()
+    text = compiled.as_text()
+    assert "gather(" in text
+    assert "scatter(" not in text
